@@ -15,9 +15,9 @@ Two acceptance bars, measured here:
 Also reported: the values-only fast path, the Pallas (max,+) backend on a
 small grid (values AND λ — the argmax-emitting kernel, no segment
 redirect), the content-hash cache hit, AOT compile times of the λ-bearing
-segment layouts (two-pass vs fused vs values-only), and a forced
-multi-device CPU-mesh smoke proving sharded runs bit-equal single-device
-ones.
+segment layouts (two-pass vs fused vs values-only), and a multi-device
+smoke proving sharded runs bit-equal single-device ones (on the host's own
+devices, or a forced CPU mesh in a CPU-only child).
 
 CLI (used by CI)::
 
@@ -144,8 +144,8 @@ def variant_study(out, n_scenarios=STUDY_SCENARIOS):
 
 
 def pallas_backend(out, n_scenarios=64):
-    # pallas (max,+) inner-scatter backend, small graph + grid (interpret
-    # mode off-TPU emulates the kernel, so keep this a smoke-scale number)
+    # pallas (max,+) inner-scatter backend, small graph + grid (on the CPU
+    # backend the kernel runs in interpret mode, so keep this smoke-scale)
     p = cluster_params(L_us=3.0, o_us=5.0)
     g_small = synth.cg_like(2, 2, 3, params=p)
     eng_p = sweep.Engine(g_small, params=p, policy=sweep.ExecPolicy(cache=None))
@@ -187,7 +187,6 @@ def lam_compile(out, n_scenarios=256):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.sweep import engine as sweep_engine
 
@@ -203,7 +202,7 @@ def lam_compile(out, n_scenarios=256):
 
     def compile_ms(want_lam, fused=False, repeats=2):
         best = np.inf
-        with enable_x64():
+        with jax.enable_x64():
             arrs = eng._arrays("segment")
             L, GS = jnp.asarray(Lmat), jnp.asarray(GSmat)
             for _ in range(repeats):
@@ -792,56 +791,66 @@ def resilience(out, smoke: bool = False):
                  f"rel_err_max={err_max:.2e}"))
 
 
-SHARD_SMOKE_PROG = """
-import numpy as np
-from repro.core import synth
-from repro.core.loggps import cluster_params
-from repro import sweep
-p = cluster_params(L_us=3.0, o_us=5.0)
-variants = sweep.collective_variants(
-    lambda a: synth.allreduce_chain(8, 1, params=p, algo=a),
-    ["ring", "recursive_doubling"], p)
-meng = sweep.MultiSweepEngine.from_variants(variants, cache=None)
-grid = sweep.latency_grid(p, np.linspace(0.0, 40.0, {S}))
-base = meng.run(grid)
-sh = meng.run(grid, shard=True)
-assert np.array_equal(base.T, sh.T), "sharded T diverged"
-assert np.array_equal(base.lam, sh.lam), "sharded lam diverged"
-g = synth.stencil2d(3, 3, 3, params=p)
-eng = sweep.SweepEngine(g, p, cache=None)
-b1 = eng.run(grid)
-s1 = eng.run(grid, shard=True)
-assert np.array_equal(b1.T, s1.T) and np.array_equal(b1.lam, s1.lam)
-p1 = eng.run(grid, backend="pallas")
-p2 = eng.run(grid, backend="pallas", shard=True)
-assert np.array_equal(p1.T, p2.T) and np.array_equal(p1.lam, p2.lam)
-print("OK")
-"""
+def _shard_smoke(S: int) -> None:
+    """Multi-graph sweeps sharded on the MultiPlan graph axis and
+    single-graph sweeps sharded on the scenario axis must be bit-equal to
+    single-device runs, on both backends."""
+    p = cluster_params(L_us=3.0, o_us=5.0)
+    variants = sweep.collective_variants(
+        lambda a: synth.allreduce_chain(8, 1, params=p, algo=a),
+        ["ring", "recursive_doubling"], p)
+    grid = sweep.latency_grid(p, np.linspace(0.0, 40.0, S))
+    pol = sweep.ExecPolicy(cache=None)
+    meng = sweep.Engine([(v.graph, v.params) for v in variants],
+                        names=[v.name for v in variants], policy=pol)
+    base = meng.run(grid)
+    sh = meng.run(grid, shard=True)
+    assert np.array_equal(base.T, sh.T), "sharded T diverged"
+    assert np.array_equal(base.lam, sh.lam), "sharded lam diverged"
+    eng = sweep.Engine(synth.stencil2d(3, 3, 3, params=p), params=p,
+                       policy=pol)
+    for backend in ("segment", "pallas"):
+        b1 = eng.run(grid, backend=backend)
+        s1 = eng.run(grid, backend=backend, shard=True)
+        assert np.array_equal(b1.T, s1.T), backend
+        assert np.array_equal(b1.lam, s1.lam), backend
 
 
 def sharded(out, n_scenarios=16, ndev=2):
-    """shard_map smoke: a forced {ndev}-device CPU mesh (subprocess — the
-    XLA flag must be set before jax initializes) runs multi-graph sweeps
-    sharded on the MultiPlan graph axis and single-graph sweeps sharded on
-    the scenario axis; results must be bit-equal to single-device runs on
-    both backends."""
+    """shard_map smoke on ``ndev`` devices.  With that many real devices
+    (a TPU host) it runs in this process, on the chips this process
+    already holds.  Otherwise a child process gets a forced ``ndev``-device
+    CPU mesh (``JAX_PLATFORMS=cpu`` — it never needs an accelerator, so a
+    parent holding the chip cannot block it; the XLA flag must be set
+    before JAX initializes, hence the child)."""
     import os
     import pathlib
     import subprocess
     import sys
 
-    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-    env = {**os.environ,
-           "PYTHONPATH": src + os.pathsep + os.environ.get("PYTHONPATH", ""),
-           "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
-                         f" --xla_force_host_platform_device_count={ndev}")}
+    import jax
+
     t0 = time.perf_counter()
-    res = subprocess.run([sys.executable, "-c",
-                          SHARD_SMOKE_PROG.format(S=n_scenarios)],
-                         capture_output=True, text=True, env=env)
-    assert res.returncode == 0 and res.stdout.strip() == "OK", res.stderr
+    if len(jax.devices()) >= ndev:
+        _shard_smoke(n_scenarios)
+        where = f"{jax.devices()[0].platform}"
+    else:
+        root = pathlib.Path(__file__).resolve().parents[1]
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
+               "PYTHONPATH": os.pathsep.join(
+                   [str(root / "src"), str(root),
+                    os.environ.get("PYTHONPATH", "")]),
+               "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
+                             f" --xla_force_host_platform_device_count={ndev}")}
+        res = subprocess.run(
+            [sys.executable, "-c",
+             "from benchmarks.bench_sweep import _shard_smoke; "
+             f"_shard_smoke({int(n_scenarios)}); print('OK')"],
+            capture_output=True, text=True, env=env)
+        assert res.returncode == 0 and res.stdout.strip() == "OK", res.stderr
+        where = "forced-cpu"
     out(csv_line(f"sweep.sharded.{ndev}dev", (time.perf_counter() - t0) * 1e6,
-                 f"scenarios={n_scenarios};bit_equal=1"))
+                 f"scenarios={n_scenarios};devices={where};bit_equal=1"))
 
 
 def run(out, smoke: bool = False):
@@ -897,6 +906,8 @@ def main(argv=None):
         records.append(line)
 
     from repro import obs
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.trace:
         obs.enable()
     print("name,us_per_call,derived")

@@ -31,10 +31,13 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+
     from . import (bench_collectives, bench_explore, bench_placement,
                    bench_solver_speed, bench_sweep, bench_tolerance,
                    bench_topology, bench_validation)
 
+    setup_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod in (bench_solver_speed, bench_validation, bench_tolerance,

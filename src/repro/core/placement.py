@@ -242,18 +242,28 @@ def _place_batched(g, phi, params, pi0, max_iters, verbose, scenario_points,
     st.update({"cost_eval": cost_eval, "steps": 0, "plan_compiles": 0,
                "engine_calls": 0, "candidates": 0, "scalar_fallbacks": 0})
 
-    base_plan, eng = None, None
+    # engine='auto' evaluates candidates with the exact scalar forward
+    # only when JAX is not installed; any other failure raises
+    from .sensitivity import jax_missing
+    eng, use_jax = None, True
     if cost_eval == "patch":
-        try:
-            base_plan = compile_plan(g)
-            st["plan_compiles"] += 1
-            eng = Engine(base_plan,
-                         policy=(policy if policy is not None else
-                                 ExecPolicy(backend=backend, cache=cache)))
-        except Exception:
-            if engine == "sweep":
-                raise
-            base_plan, eng = None, None    # scalar fallback per step
+        st["plan_compiles"] += 1
+        eng = Engine(compile_plan(g),
+                     policy=(policy if policy is not None else
+                             ExecPolicy(backend=backend, cache=cache)))
+
+    def device_objectives(extras):
+        if eng is None:
+            fs = _candidate_objectives(g, scen_batch, extras, backend)
+            st["plan_compiles"] += len(extras)
+        else:
+            # zero-recompile path: K candidate cost blocks through the
+            # once-compiled plan (structure unbatched inside the vmap; raw
+            # extras → the engine patches only its backend's view)
+            fs = eng.run(Query(scenarios=scen_batch, costs=np.stack(extras),
+                               outputs=("T",))).T.mean(axis=1)
+        st["engine_calls"] += 1
+        return fs
 
     def forwards(pi_):
         ex = mapping_edge_cost(g, phi, pi_)
@@ -290,29 +300,15 @@ def _place_batched(g, phi, params, pi0, max_iters, verbose, scenario_points,
             pc[ci], pc[cj] = pc[cj], pc[ci]
             extras.append(mapping_edge_cost(g, phi, pc))
         st["candidates"] += len(cand)
-        try:
-            if eng is not None:
-                # zero-recompile path: K candidate cost blocks through the
-                # once-compiled plan (structure unbatched inside the vmap;
-                # raw extras → the engine patches only its backend's view)
-                res = eng.run(Query(scenarios=scen_batch,
-                                    costs=np.stack(extras),
-                                    outputs=("T",)))
-                fs = res.T.mean(axis=1)
-                st["engine_calls"] += 1
-            elif cost_eval == "rebuild":
-                fs = _candidate_objectives(g, scen_batch, extras, backend)
-                st["plan_compiles"] += len(extras)
-                st["engine_calls"] += 1
-            else:
-                raise ImportError("no warm sweep engine")
-        except Exception:
-            # same 'auto' contract as core.sensitivity: degrade to the
-            # exact scalar evaluation on ANY sweep-path failure (no JAX,
-            # broken backend, OOM on the packed plan) unless the caller
-            # forced engine='sweep'
-            if engine == "sweep":
-                raise
+        fs = None
+        if use_jax:
+            try:
+                fs = device_objectives(extras)
+            except ImportError as e:
+                if engine == "sweep" or not jax_missing(e):
+                    raise
+                use_jax = False
+        if fs is None:
             fs = np.asarray([np.mean([plan.forward(pt, extra_edge_cost=ex).T
                                       for pt in pts]) for ex in extras])
             st["scalar_fallbacks"] += 1
@@ -378,9 +374,6 @@ def place(g: ExecutionGraph, phi: ArchTopology, params: Optional[LogGPS] = None,
         backend = policy.backend
         cache = policy.cache
     if backend not in ("segment", "pallas"):
-        # validate eagerly: under engine='auto' a typo would otherwise be
-        # swallowed by the per-step scalar fallback and silently ignore
-        # the caller's explicit backend choice
         raise ValueError(f"backend must be 'segment' or 'pallas', "
                          f"got {backend!r}")
     params = params or LogGPS(L=(0.0,), G=(0.0,), o=0.5, S=1e18)

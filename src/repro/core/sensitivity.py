@@ -13,11 +13,11 @@ Multi-point queries dispatch to the batched scenario-sweep engine
 it pays off — ≥ :data:`SWEEP_MIN_POINTS` curve points, ≥
 :data:`SWEEP_MIN_DEGRADATIONS` tolerance levels, or large graphs for the
 breakpoint search.  ``engine="scalar"`` forces the numpy path,
-``engine="sweep"`` forces (and surfaces errors from) the batched path;
-the default ``"auto"`` falls back to scalar if JAX is unavailable
-(silently — that is an expected install state) and warns once before
-falling back on any *other* engine failure, so real sweep bugs never
-vanish into a slow-but-correct scalar loop.
+``engine="sweep"`` forces the batched path; the default ``"auto"`` takes
+the scalar path only when JAX is not installed (an expected install
+state).  Any other failure of the batched path raises under every
+engine setting: a broken device path must never be served, and timed, as
+the slow-but-correct scalar loop.
 
 How the batched path executes is one object, not loose kwargs: pass
 ``policy=`` (a :class:`repro.sweep.api.ExecPolicy`) to pick the backend,
@@ -85,45 +85,20 @@ def _check_engine_arg(engine: str) -> None:
                          f"got {engine!r}")
 
 
-def _warn_sweep_fallback(where: str, err: Exception) -> None:
-    """One-time RuntimeWarning when ``engine="auto"`` abandons the batched
-    path for a reason other than "JAX isn't installed".  A bare silent
-    fallback here used to swallow real engine bugs — results stayed
-    plausible (the scalar path is correct) while every sweep quietly ran
-    orders of magnitude slower.  (Keyed through the sweep engine's shared
-    warn-once registry; only reachable after ``repro.sweep`` imported.)"""
-    from repro.sweep.engine import _warn_once
-    _warn_once(
-        ("sensitivity-fallback", where, type(err).__name__),
-        f"sensitivity.{where}: batched sweep engine failed with "
-        f"{type(err).__name__}: {err} — falling back to the scalar "
-        "loop for this and later calls; pass engine='sweep' to surface "
-        "the error")
+def jax_missing(err: BaseException) -> bool:
+    """True only for "JAX is not installed" — the one state in which
+    ``engine="auto"`` takes the scalar path.  Any other ``ImportError``
+    (an API that moved inside an installed JAX, a kernel that fails to
+    import) is a broken device path and must surface."""
+    return isinstance(err, ModuleNotFoundError) and err.name == "jax"
 
 
-def _sweep_engine_or_fallback(g: ExecutionGraph, params: LogGPS,
-                              engine: str, where: str, policy=None):
-    """Resolve the batched engine for one dispatch site.
-
-    ImportError (JAX not installed) is an expected state → quiet ``None``.
-    Any other construction failure (compile_plan, rank_of_class raising,
-    …) follows the same contract as run-time failures: surface it under
-    ``engine="sweep"``, warn once and fall back under ``"auto"``.
-    """
-    try:
-        return _sweep_engine(g, params, policy)
-    except ImportError:
-        if policy is not None:
-            # an explicit policy is an explicit ask for the batched path —
-            # honoring it with a silent scalar loop would discard the
-            # backend/λ-mode contract the caller pinned
-            raise
-        return None
-    except Exception as e:  # noqa: BLE001 — deliberate auto-fallback
-        if engine == "sweep" or policy is not None:
-            raise
-        _warn_sweep_fallback(where, e)
-        return None
+def _scalar_ok(err: BaseException, engine: str, policy) -> bool:
+    """Whether a batched-path failure may be served by the scalar loop:
+    only under plain ``engine="auto"`` (no explicit policy, which pins a
+    backend/λ-mode contract the scalar loop cannot honor) and only when
+    JAX is absent."""
+    return engine == "auto" and policy is None and jax_missing(err)
 
 
 def _params_memo_key(g: ExecutionGraph, params: LogGPS) -> tuple:
@@ -162,7 +137,7 @@ def _params_memo_key(g: ExecutionGraph, params: LogGPS) -> tuple:
 
 
 def _sweep_engine(g: ExecutionGraph, params: LogGPS, policy=None):
-    """Build (or reuse) a batched engine; None if JAX is unavailable.
+    """Build (or reuse) a batched engine.
 
     Compiled engines are memoized on the graph object per parameter set
     (content-keyed, see :func:`_params_memo_key`) and per execution
@@ -173,10 +148,7 @@ def _sweep_engine(g: ExecutionGraph, params: LogGPS, policy=None):
     :class:`repro.sweep.api.ExecPolicy` builds the unified
     :class:`repro.sweep.api.Engine` directly.
     """
-    try:
-        from repro.sweep import SweepEngine
-    except ImportError:
-        return None
+    from repro.sweep import SweepEngine
     memo = getattr(g, "_sweep_engines", None)
     if memo is None:
         memo = {}
@@ -210,22 +182,13 @@ def latency_curve(g: ExecutionGraph, params: LogGPS, deltas: Sequence[float],
     if want_sweep:
         try:
             from repro.sweep import latency_grid
-        except ImportError:
-            if policy is not None:
-                raise                  # explicit policy: never silent scalar
-            latency_grid = None              # jax unavailable: quiet scalar path
-        eng = (None if latency_grid is None else
-               _sweep_engine_or_fallback(g, params, engine, "latency_curve",
-                                         policy))
-        if eng is not None:
-            try:
-                res = eng.run(latency_grid(params, deltas_arr, cls=cls))
-                return LatencyCurve(deltas=deltas_arr, T=res.T,
-                                    lam=res.lam[:, cls], rho=res.rho[:, cls])
-            except Exception as e:
-                if engine == "sweep" or policy is not None:
-                    raise
-                _warn_sweep_fallback("latency_curve", e)
+            res = _sweep_engine(g, params, policy).run(
+                latency_grid(params, deltas_arr, cls=cls))
+            return LatencyCurve(deltas=deltas_arr, T=res.T,
+                                lam=res.lam[:, cls], rho=res.rho[:, cls])
+        except ImportError as e:
+            if not _scalar_ok(e, engine, policy):
+                raise
     plan = plan or dag.LevelPlan(g)
     Ts, lams, rhos = [], [], []
     for d in deltas_arr:
@@ -310,10 +273,9 @@ def resilience_curve(g: ExecutionGraph, params: LogGPS, faults: Sequence,
     p50/p95/p99 over the distribution.
 
     Device faults need the structural (B) axis and therefore the batched
-    engine; the scalar fallback (JAX unavailable, or
-    ``engine="scalar"``) handles straggler and link faults only and
-    raises otherwise.  Sharded policies are rejected by the engine when
-    the B axis is populated.
+    engine; the scalar path (JAX not installed, or ``engine="scalar"``)
+    handles straggler and link faults only and raises otherwise.  Sharded
+    policies are rejected by the engine when the B axis is populated.
     """
     _check_engine_arg(engine)
     faults = list(faults)
@@ -336,32 +298,17 @@ def resilience_curve(g: ExecutionGraph, params: LogGPS, faults: Sequence,
     if engine != "scalar":
         try:
             from repro.sweep.api import ExecPolicy, Query
-        except ImportError:
-            if policy is not None or engine == "sweep" or has_device:
-                raise              # no scalar path can serve these
-            Query = None
-        if Query is not None:
             # an explicit unified-Engine policy (the legacy shim has no
-            # structure axis); construction/run failures fall back to the
-            # scalar loop only under plain "auto" with no device faults
-            try:
-                eng = _sweep_engine(g, params,
-                                    policy if policy is not None
-                                    else ExecPolicy())
-                if eng is not None:
-                    ax = fault_axes(g, params, faults, plan=eng.plan)
-                    res = eng.run(Query(scenarios=ax.scenarios,
-                                        costs=ax.extras,
-                                        structure=ax.structure))
-                elif policy is not None or engine == "sweep" or has_device:
-                    raise ImportError(
-                        "resilience_curve: the batched sweep engine needs "
-                        "JAX, which is unavailable")
-            except Exception as e:
-                if engine == "sweep" or policy is not None or has_device:
-                    raise
-                _warn_sweep_fallback("resilience_curve", e)
-                res = None
+            # structure axis)
+            eng = _sweep_engine(g, params,
+                                policy if policy is not None
+                                else ExecPolicy())
+            ax = fault_axes(g, params, faults, plan=eng.plan)
+            res = eng.run(Query(scenarios=ax.scenarios, costs=ax.extras,
+                                structure=ax.structure))
+        except ImportError as e:
+            if has_device or not _scalar_ok(e, engine, policy):
+                raise              # no scalar path can serve these
 
     if res is not None:
         def cell_T(b, k, s):
@@ -376,7 +323,7 @@ def resilience_curve(g: ExecutionGraph, params: LogGPS, faults: Sequence,
         T0 = cell_T(0, 0, 0)
         T_fault = np.asarray([cell_T(*c) for c in ax.cells])
         names, cells = ax.names, ax.cells
-    else:                              # scalar fallback: K/S families only
+    else:                              # scalar path: K/S families only
         if has_device:
             raise ValueError(
                 "device faults need the batched sweep engine (structural "
@@ -427,20 +374,11 @@ def latency_tolerance(g: ExecutionGraph, params: LogGPS,
     if want_sweep:
         try:
             from repro.sweep import tolerance_batched
-        except ImportError:
-            if policy is not None:
-                raise                  # explicit policy: never silent scalar
-            tolerance_batched = None              # jax unavailable: quiet scalar path
-        eng = (None if tolerance_batched is None else
-               _sweep_engine_or_fallback(g, params, engine,
-                                         "latency_tolerance", policy))
-        if eng is not None:
-            try:
-                return tolerance_batched(eng, params, degr, cls=cls)
-            except Exception as e:
-                if engine == "sweep" or policy is not None:
-                    raise
-                _warn_sweep_fallback("latency_tolerance", e)
+            return tolerance_batched(_sweep_engine(g, params, policy),
+                                     params, degr, cls=cls)
+        except ImportError as e:
+            if not _scalar_ok(e, engine, policy):
+                raise
     plan = plan or dag.LevelPlan(g)
     return {p: dag.tolerance(g, params, p, cls=cls, plan=plan)
             for p in degr}
@@ -456,9 +394,9 @@ def bandwidth_curve(g: ExecutionGraph, params: LogGPS,
     Both paths resolve per-edge gap shares through
     :func:`repro.core.graph.edge_gap_shares` — build-time recorded shares
     are authoritative, unknown shares reconstruct from ``params`` — so the
-    compiled sweep path and this scalar fallback always agree.  The sweep
+    compiled sweep path and the scalar path always agree.  The sweep
     engine re-scales the shares inside the compiled forward; the scalar
-    fallback feeds ``egap·(γ−1)`` through ``extra_edge_cost`` — no graph
+    path feeds ``egap·(γ−1)`` through ``extra_edge_cost`` — no graph
     rebuild either way.
 
     Raises ``ValueError`` if any resolved share is non-finite (an inf/NaN
@@ -487,22 +425,13 @@ def bandwidth_curve(g: ExecutionGraph, params: LogGPS,
     if want_sweep:
         try:
             from repro.sweep import bandwidth_grid
-        except ImportError:
-            if policy is not None:
-                raise                  # explicit policy: never silent scalar
-            bandwidth_grid = None              # jax unavailable: quiet scalar path
-        eng = (None if bandwidth_grid is None else
-               _sweep_engine_or_fallback(g, params, engine, "bandwidth_curve",
-                                         policy))
-        if eng is not None:
-            try:
-                res = eng.run(bandwidth_grid(params, gs, cls=cls))
-                return LatencyCurve(deltas=gs, T=res.T,
-                                    lam=res.lam[:, cls], rho=res.rho[:, cls])
-            except Exception as e:
-                if engine == "sweep" or policy is not None:
-                    raise
-                _warn_sweep_fallback("bandwidth_curve", e)
+            res = _sweep_engine(g, params, policy).run(
+                bandwidth_grid(params, gs, cls=cls))
+            return LatencyCurve(deltas=gs, T=res.T,
+                                lam=res.lam[:, cls], rho=res.rho[:, cls])
+        except ImportError as e:
+            if not _scalar_ok(e, engine, policy):
+                raise
     plan = plan or dag.LevelPlan(g)
     scale = np.where(egclass == cls, 1.0, 0.0) * egap
     Ts, lams, rhos = [], [], []
@@ -530,18 +459,9 @@ def critical_latencies(g: ExecutionGraph, params: LogGPS, L_min: float,
     if want_sweep:
         try:
             from repro.sweep import breakpoints_batched
-        except ImportError:
-            if policy is not None:
-                raise                  # explicit policy: never silent scalar
-            breakpoints_batched = None              # jax unavailable: quiet scalar path
-        eng = (None if breakpoints_batched is None else
-               _sweep_engine_or_fallback(g, params, engine,
-                                         "critical_latencies", policy))
-        if eng is not None:
-            try:
-                return breakpoints_batched(eng, params, L_min, L_max, cls=cls)
-            except Exception as e:
-                if engine == "sweep" or policy is not None:
-                    raise
-                _warn_sweep_fallback("critical_latencies", e)
+            return breakpoints_batched(_sweep_engine(g, params, policy),
+                                       params, L_min, L_max, cls=cls)
+        except ImportError as e:
+            if not _scalar_ok(e, engine, policy):
+                raise
     return dag.breakpoints(g, params, L_min, L_max, cls=cls, plan=plan)

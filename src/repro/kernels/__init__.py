@@ -9,9 +9,11 @@
                       execution graphs (parameter sweeps batch over the
                       lane dimension).
 
-Kernels are written against TPU BlockSpec/VMEM tiling and validated in
-``interpret=True`` mode on CPU (this container has no TPU); ``ops.py``
-wrappers auto-select interpret mode off-TPU.
+Kernels are written against TPU BlockSpec/VMEM tiling and compile for
+the TPU; on the CPU backend they run in ``interpret=True`` mode (tests).
+``maxplus/ops.py`` resolves that in one place and raises on any other
+platform; ``tests/test_tpu_compile.py`` compiles every maxplus entry point
+for a described v5e chip.
 """
 
 from .flash_attention.ops import flash_attention  # noqa: F401

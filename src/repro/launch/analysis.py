@@ -808,6 +808,8 @@ def main(argv=None):
     if not args.demo:
         raise SystemExit("no workload source: pass --demo (or embed "
                          "AnalysisService in your own driver)")
+    from .compile_cache import setup_compile_cache
+    setup_compile_cache()
     svc = _demo_service(args.backend)
     t0 = time.perf_counter()
     info = svc.warm()

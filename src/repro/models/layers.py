@@ -254,9 +254,8 @@ def decode_attention_sharded(q, k, v, q_offset, kv_len):
         return jnp.moveaxis(o, 1, 2).astype(q_l.dtype)         # [B,Tq,H,dv]
 
     from jax.sharding import PartitionSpec as PSpec
-    from repro.parallel.compat import shard_map
     bspec = b_axes if b_axes else None
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PSpec(bspec, None, None, None),
                   PSpec(bspec, kv_axes, None, None),

@@ -51,11 +51,8 @@ def pipeline_run(stage_fn: Callable, params_stage, x_micro, *, axis: str,
         return (out,), out
 
     zero = jnp.zeros_like(x_micro[0])
-    # mark the carry as axis-varying (each stage holds different data);
-    # pvary only exists on JAX versions with varying-manual-axes tracking —
-    # older releases don't track per-axis variance, so it's a no-op there
-    if hasattr(jax.lax, "pvary"):
-        zero = jax.lax.pvary(zero, (axis,))
+    # mark the carry as axis-varying (each stage holds different data)
+    zero = jax.lax.pcast(zero, (axis,), to="varying")
     (_,), outs = jax.lax.scan(tick, (zero,), jnp.arange(T))
     # last stage emits microbatch m at tick m + (n_stages-1)
     take = jnp.arange(n_micro) + (n_stages - 1)
@@ -80,10 +77,8 @@ def build_pipeline_fn(stage_fn: Callable, mesh, axis: str = "pod"):
                 jnp.where(idx == n_stages - 1, out, jnp.zeros_like(out)), axis)
             return out
 
-        from .compat import shard_map
-
         pspecs = jax.tree.map(lambda _: PSpec(axis), params_stages)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(pspecs, PSpec()),
             out_specs=PSpec(),

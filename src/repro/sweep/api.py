@@ -49,6 +49,7 @@ import hashlib
 import os
 import threading
 import time
+import warnings
 from collections import OrderedDict
 from typing import Optional, Sequence, Union
 
@@ -305,6 +306,12 @@ class Result:
     lam_mode: str = "exact"
     #: [K?, S] fixed-point iteration counts (congestion dispatches only)
     congestion_iters: Optional[np.ndarray] = None
+    #: float dtype the forward computed in ("float64" / "float32")
+    dtype: str = ""
+    #: platform of the devices that produced T ("tpu", "cpu", ...)
+    platform: str = ""
+    #: how many devices the forward spanned (> 1 only when sharded)
+    devices: int = 0
 
     @property
     def S(self) -> int:
@@ -338,7 +345,8 @@ class Result:
             rho=None if self.rho is None else self.rho[g].copy(),
             axes=self.axes[1:], scenarios=scen,
             backend=self.backend, from_cache=self.from_cache,
-            lam_mode=self.lam_mode)
+            lam_mode=self.lam_mode, dtype=self.dtype,
+            platform=self.platform, devices=self.devices)
 
     def split(self) -> dict:
         """{name: per-graph (or per-variant) Result} — the variant-study
@@ -388,6 +396,13 @@ def _copy(res: Result, **replace) -> Result:
         rho=None if res.rho is None else res.rho.copy(),
         congestion_iters=(None if res.congestion_iters is None
                           else res.congestion_iters.copy()), **replace)
+
+
+def _ran_on(T) -> tuple:
+    """(dtype, platform, device count) of a forward's device output — what
+    really ran, stamped on the Result so callers can assert it."""
+    devs = T.devices()
+    return str(T.dtype), next(iter(devs)).platform, len(devs)
 
 
 def _variant_names(sb: StructureBatch) -> tuple:
@@ -565,7 +580,6 @@ class Engine:
             mdb = int(env) if env else None
         if mdb is not None:
             self.MAX_DENSE_BYTES = int(mdb)
-        self._warned: set = set()     # per-instance warn-once registry
         plan = multi = plans = None
         sparse = structure = None
         if isinstance(graphs, StructureBatch):
@@ -614,13 +628,12 @@ class Engine:
                             "dtype='float32' pins the pallas contract — "
                             "pass backend='sparse' (float64) explicitly, "
                             "or raise Engine.MAX_DENSE_BYTES")
-                    _eng._warn_once(
-                        ("auto-sparse",),
+                    warnings.warn(
                         f"graph's padded dense envelope needs ~{est >> 20} "
                         f"MiB (> {self.MAX_DENSE_BYTES >> 20} MiB); "
                         "auto-switching to backend='sparse' (compact slot "
                         "lists, T/λ bit-identical to segment)",
-                        registry=self._warned)
+                        RuntimeWarning, stacklevel=2)
                     self.policy = self.policy.replace(backend="sparse")
                     sparse = compile_sparse(graphs, params)
                 else:
@@ -944,33 +957,6 @@ class Engine:
                     "for the per-class (α, β) congestion registry — "
                     "construct Engine(graph_or_plan, params=...)")
 
-        # pallas λ needs the argmax kernel; if it cannot even be built on
-        # this install, say so ONCE and fall back — never silently ignore
-        # an explicit backend choice (fd λ runs the plain values kernel,
-        # so it needs no probe)
-        if kind == "pallas" and want_lam and not fd:
-            try:
-                _eng._get_forward("pallas", True, self.multi is not None)
-            except ImportError as e:
-                if pol.dtype != "auto":
-                    # the caller PINNED the float32 contract; a segment
-                    # fallback would return float64 results under a policy
-                    # that validate() rejects — surface instead of override
-                    raise ImportError(
-                        "backend='pallas' λ needs the argmax (max,+) "
-                        f"kernel, which failed to import ({e}); cannot "
-                        "fall back to segment because dtype="
-                        f"{pol.dtype!r} pins the pallas float32 contract"
-                        ) from e
-                _eng._warn_once(
-                    ("override", "pallas-lam"),
-                    "backend='pallas' with compute_lam=True needs the "
-                    f"argmax (max,+) kernel, which failed to import "
-                    f"({e}); overriding to backend='segment'",
-                    registry=self._warned)
-                kind = "segment"
-                pol = dataclasses.replace(pol, backend="segment")
-
         with _span("sweep.canonicalize"):
             batches = self._batches(scenarios)
         if costs is not None:
@@ -1245,8 +1231,7 @@ class Engine:
         t0 = time.perf_counter()
         with _span("sweep.execute", backend=kind, axes=axes_s):
             if sparse:
-                from jax.experimental import enable_x64
-                with enable_x64():
+                with _eng._jax().enable_x64():
                     arrs = self._arrays("sparse")
                     # dtype="float32" pins the Pallas slot-list kernel
                     # flavor; float64 (native) is the bit-exact jnp
@@ -1259,11 +1244,11 @@ class Engine:
                         sparse_dims=(sp.Emax_lv, sp.Vmax_lv))
                     T, lam = fwd(*arrs, jnp.asarray(Lmat),
                                  jnp.asarray(GSmat))
+                    ran = _ran_on(T)
                     T = np.asarray(T).astype(np.float64)
                     lam = np.asarray(lam).astype(np.float64)
             elif seg:
-                from jax.experimental import enable_x64
-                with enable_x64():
+                with _eng._jax().enable_x64():
                     arrs = self._arrays("congestion" if cong else "segment")
                     if has_K:
                         cost_arrs = stage_costs(arrs)
@@ -1294,6 +1279,7 @@ class Engine:
                             mesh, **fwd_kw)
                         T, lam = fwd(*args, jnp.asarray(Lmat),
                                      jnp.asarray(GSmat))
+                    ran = _ran_on(T)
                     T = np.asarray(T)
                     lam = np.asarray(lam)
             else:
@@ -1309,6 +1295,7 @@ class Engine:
                                         has_G, False, mesh, **fwd_kw)
                 T, lam = fwd(*args, jnp.asarray(Lmat, dtype=jnp.float32),
                              jnp.asarray(GSmat, dtype=jnp.float32))
+                ran = _ran_on(T)
                 T = np.asarray(T).astype(np.float64)
                 lam = np.asarray(lam).astype(np.float64)
                 if has_G and has_K:               # [K, G, ...] → [G, K, ...]
@@ -1373,7 +1360,8 @@ class Engine:
                       names=_variant_names(sb) if has_B else self.names,
                       lam_mode=pol.lam if want_lam else "exact",
                       congestion_iters=(None if iters is None
-                                        else np.array(iters)))
+                                        else np.array(iters)),
+                      dtype=ran[0], platform=ran[1], devices=ran[2])
 
 
 def run(query: Query, policy: Optional[ExecPolicy] = None,

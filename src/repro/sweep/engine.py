@@ -162,22 +162,14 @@ def _jax():
     return jax
 
 
-_WARNED: set = set()
+#: latency-count × latency matmuls run at full precision: the TPU's default
+#: f32 matmul rounds its inputs to bfloat16, which would put ~0.4% error on
+#: every edge cost (CPU results are unchanged)
+_HIGHEST = "highest"
 
 
-def _warn_once(key: tuple, message: str, registry: Optional[set] = None) -> None:
-    """Emit a RuntimeWarning once per key (backend overrides, engine
-    fallbacks) — loud enough to see, quiet enough for sweep loops.
-
-    ``registry`` scopes the once-ness: engines pass their own set so a
-    backend override warns once per engine *instance* (a fresh engine in a
-    new study warns again) rather than once per process.
-    """
-    reg = _WARNED if registry is None else registry
-    if key not in reg:
-        reg.add(key)
-        import warnings
-        warnings.warn(message, RuntimeWarning, stacklevel=3)
+def _dot(a, b):
+    return _jax().numpy.matmul(a, b, precision=_HIGHEST)
 
 
 def _make_segment_one(want_lam: bool, fused: bool = False):
@@ -229,7 +221,7 @@ def _make_segment_one(want_lam: bool, fused: bool = False):
                 vlink, lscale = link
                 gse = gse * lscale[vlink[lv]]
             w = (vconst[lv] + vgap[lv] * (gse - 1.0)
-                 + vlat[lv] @ Lrow)
+                 + _dot(vlat[lv], Lrow))
             cand = jnp.where(vmaskd[lv], t_end[vsrc[lv]] + w, -BIG)
             ts = jnp.maximum(jnp.max(cand, axis=1), 0.0)   # t_start ≥ 0
             return cand, ts
@@ -575,7 +567,7 @@ def _dense_core(want_lam: bool = False):
         def edge_cand(lv, t_end):
             gse = GSmat[:, egclass[lv]].T                       # [Emax, S]
             w = (econst[lv][:, None] + egap[lv][:, None] * (gse - 1.0)
-                 + elat[lv] @ Lmat.T)
+                 + _dot(elat[lv], Lmat.T))
             cand = t_end[esrc[lv]] + w
             return jnp.where(emask[lv][:, None], cand,
                              -BIG).astype(jnp.float32)
@@ -670,7 +662,8 @@ def _dense_core_multi(want_lam: bool = False):
                 jnp.swapaxes(GSmat, 1, 2), egclass[:, lv][:, :, None], axis=1)
             w = (econst[:, lv][:, :, None]
                  + egap[:, lv][:, :, None] * (gse - 1.0)
-                 + jnp.einsum("gec,gsc->ges", elat[:, lv], Lmat))
+                 + jnp.einsum("gec,gsc->ges", elat[:, lv], Lmat,
+                              precision=_HIGHEST))
             cand = jnp.take_along_axis(t_end, esrc[:, lv][:, :, None],
                                        axis=1) + w
             return jnp.where(emask[:, lv][:, :, None], cand,
@@ -787,7 +780,7 @@ def _make_sparse_one(want_lam: bool, Emax_lv: int, Vmax_lv: int):
             w = (dsl(econst, (e0,), (Emax_lv,))
                  + dsl(egap, (e0,), (Emax_lv,))
                  * (gsrow[dsl(egclass, (e0,), (Emax_lv,))] - 1.0)
-                 + dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc)) @ Lrow)
+                 + _dot(dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc)), Lrow))
             cand = jnp.where(em, t[es] + w, -BIG)
             dloc = dsl(edst, (e0,), (Emax_lv,)) - v_ptr[lv]
             seg = jax.ops.segment_max(cand, dloc, num_segments=Vmax_lv)
@@ -872,11 +865,16 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
     level scatter-max runs the slot-list (max,+) kernel with scenarios on
     the 128-wide lane axis (no per-scenario vmap) and the in-kernel
     lexicographic (value, cumulative-slope key, ordinal) argmax drives the
-    λ backtrace — the sparse twin of ``_dense_core``.  Float32
-    accumulators ⇒ T within ~1e-6 relative of the float64 slot-list
-    forward; the same exact-tie caveat as the dense kernel applies
-    (tolerance-grouped tie sets aren't associative across blocked
-    reductions), so segment/sparse-f64 stay the bit-exact references.
+    λ backtrace — the sparse twin of ``_dense_core``.  The kernel reduces
+    float32 candidates; its argmax then selects the candidate's exact value
+    and the level carry stays in the float dtype of the caller's scope
+    (float64 under the engine), so rounding does not compound with depth
+    (a float32 carry drifted 3.2e-5 relative over the 8192 levels of a
+    traced training step).  T comes back as float32, within ~1e-7
+    relative of the float64 slot-list forward; the same exact-tie caveat
+    as the dense kernel applies (tolerance-grouped tie sets aren't
+    associative across blocked reductions), so segment/sparse-f64 stay the
+    bit-exact references.
     """
     jax = _jax()
     jnp = jax.numpy
@@ -898,6 +896,7 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
         nv_p = vcost.shape[0]
         nc = elat.shape[1]
         S = Lmat.shape[0]
+        ft = Lmat.dtype                  # level-carry dtype (see docstring)
         vidx = jnp.arange(Vmax_lv, dtype=jnp.int32)
 
         def relax(lv, t):
@@ -908,7 +907,8 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
             w = (dsl(econst, (e0,), (Emax_lv,))[:, None]
                  + dsl(egap, (e0,), (Emax_lv,))[:, None]
                  * (jnp.take(GSmat, gcls, axis=1).T - 1.0)
-                 + dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc)) @ Lmat.T)
+                 + _dot(dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc)),
+                        Lmat.T))
             cand = jnp.where(em[:, None], t[es] + w, -BIG)   # [Emax_lv, S]
             dloc = dsl(edst, (e0,), (Emax_lv,)) - v_ptr[lv]
             return e0, es, cand, dloc
@@ -924,9 +924,13 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
                          ((0, E_pad - Emax_lv), (0, 0)))
             d = jnp.pad(dloc.astype(jnp.int32), (0, E_pad - Emax_lv),
                         constant_values=M_pad)[:, None]
-            out, idx = maxplus_slotlist_argmax(d, cf, kf, M=M_pad,
-                                               bm=bm, be=be)
-            return out[:Vmax_lv], idx[:Vmax_lv]
+            _, idx = maxplus_slotlist_argmax(d, cf, kf, M=M_pad,
+                                             bm=bm, be=be)
+            idx = idx[:Vmax_lv]
+            # the exact value of the candidate the kernel selected
+            raw = jnp.where(idx >= 0, jnp.take_along_axis(
+                cand, jnp.maximum(idx, 0), axis=0), -BIG)
+            return raw, idx
 
         def vwin(lv):
             return dsl(vcost, (v_ptr[lv],), (Vmax_lv,))
@@ -936,13 +940,12 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
                 _, _, cand, dloc = relax(lv, t)
                 raw, _ = reduce(cand, dloc, jnp.zeros_like(cand))
                 ts = jnp.maximum(raw, 0.0)
-                return dus(t, (ts + vwin(lv)[:, None]).astype(jnp.float32),
+                return dus(t, (ts + vwin(lv)[:, None]).astype(ft),
                            (v_ptr[lv], jnp.int32(0)))
 
-            t = jax.lax.fori_loop(0, nlv, body,
-                                  jnp.zeros((nv_p, S), jnp.float32))
+            t = jax.lax.fori_loop(0, nlv, body, jnp.zeros((nv_p, S), ft))
             T = jnp.max(jnp.where(valid[:, None], t, -BIG), axis=0)
-            return T, jnp.zeros((S, nc), jnp.float32)
+            return T.astype(jnp.float32), jnp.zeros((S, nc), jnp.float32)
 
         def body(lv, carry):
             t, ssum, nxt, lrow = carry
@@ -964,13 +967,12 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
             row = jnp.where(has[:, :, None], elat_w[ce], 0.0)
             v0 = v_ptr[lv]
             z = jnp.int32(0)
-            return (dus(t, (ts + vwin(lv)[:, None]).astype(jnp.float32),
-                        (v0, z)),
+            return (dus(t, (ts + vwin(lv)[:, None]).astype(ft), (v0, z)),
                     dus(ssum, ss_new.astype(jnp.float32), (v0, z)),
                     dus(nxt, nxt_row, (v0, z)),
                     dus(lrow, row.astype(jnp.float32), (v0, z, z)))
 
-        init = (jnp.zeros((nv_p, S), jnp.float32),
+        init = (jnp.zeros((nv_p, S), ft),
                 jnp.zeros((nv_p, S), jnp.float32),
                 jnp.broadcast_to(jnp.arange(nv_p, dtype=jnp.int32)[:, None],
                                  (nv_p, S)),
@@ -990,7 +992,7 @@ def _sparse_pallas_core(want_lam: bool, dims: tuple):
         _, visited = jax.lax.scan(step, vsel.astype(jnp.int32), None,
                                   length=nlv)                # [nlv, S]
         lam = jnp.sum(lrow[visited, sidx[None, :], :], axis=0)
-        return T, lam
+        return T.astype(jnp.float32), lam
 
     return fwd
 
@@ -1197,10 +1199,9 @@ def _get_forward(kind: str, want_lam: bool = False, multi: bool = False,
     else:
         core = _dense_core_axes(want_lam, multi, costs, structure)
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         in_specs, out_specs = _shard_specs(kind, multi, costs, shard_axis)
-        core = shard_map(core, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
     fn = jax.jit(core)
     _FWD_CACHE[key] = fn
     return fn

@@ -62,9 +62,8 @@ def f(g, r):
     out, nr = compressed_psum(g[0], "pod", r[0])
     return out[None], nr[None]
 
-from repro.parallel.compat import shard_map
-out, nr = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                    out_specs=(P("pod"), P("pod")))(g, res)
+out, nr = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                        out_specs=(P("pod"), P("pod")))(g, res)
 true_mean = g.mean(axis=0)
 err = np.abs(np.asarray(out[0]) - np.asarray(true_mean)).max()
 scale = np.abs(np.asarray(g)).max() / 127

@@ -286,6 +286,27 @@ def test_auto_sparse_switch(params, monkeypatch):
                      policy=sweep.ExecPolicy(dtype="float32", cache=None))
 
 
+def test_sparse_float32_does_not_drift_with_depth():
+    """The float32 slot-list flavour keeps its level carry in float64 and
+    takes the exact value of the candidate its kernel selects, so T stays
+    within one float32 rounding of the float64 forward however deep the
+    graph (a float32 carry drifted ~1e-5 relative over these 4096
+    levels)."""
+    p = cluster_params(L_us=3.0, o_us=5.0)
+    g = synth.allreduce_chain(4, 200, nbytes=2e6, comp_us=4000.0, params=p,
+                              algo="ring")
+    sp = sweep.compile_sparse(g, p)
+    grid = sweep.latency_grid(p, [0.0, 7.3, 51.1])
+
+    def run(dtype):
+        return sweep.Engine(sp, params=p, policy=sweep.ExecPolicy(
+            backend="sparse", dtype=dtype, cache=None)).run(grid)
+
+    f64, f32 = run("float64"), run("float32")
+    np.testing.assert_allclose(f32.T, f64.T, rtol=1e-7)
+    np.testing.assert_array_equal(f32.lam, f64.lam)
+
+
 def test_byte_accounting_and_gauge(fixture):
     """dense_bytes ⊃ segment_bytes ⊃ 0 (the pallas view adds the dense
     indicator; both cover the λ tie-break arrays), padding_ratio =

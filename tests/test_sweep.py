@@ -460,7 +460,7 @@ def test_two_pass_lambda_bit_identical_to_fused(params):
     """The default two-pass segment λ (next-pointer records + reverse
     pointer chase) reproduces the fused single-loop backtrace bit-for-bit —
     tie-heavy collective graphs and multi-class params included."""
-    from jax.experimental import enable_x64
+    import jax
     import jax.numpy as jnp
     p2 = tpu_pod_params(pod_size=2)
     cases = [(synth.allreduce_chain(8, 3, params=params), params),
@@ -476,7 +476,7 @@ def test_two_pass_lambda_bit_identical_to_fused(params):
         Lm[:S] = grid.L
         GS = np.repeat(grid.gscale[-1:], Sp, axis=0)
         GS[:S] = grid.gscale
-        with enable_x64():
+        with jax.enable_x64():
             fwd = sweep_engine._get_forward("segment", True, fused=True)
             Tf, lf = fwd(*eng._arrays("segment"), jnp.asarray(Lm),
                          jnp.asarray(GS))
@@ -555,28 +555,22 @@ def test_scenario_batch_validation():
 
 
 def test_auto_dispatch_warns_once_then_falls_back(params, monkeypatch):
-    """engine='auto' no longer swallows real engine bugs: a non-import
-    failure warns once (RuntimeWarning) and falls back to the scalar loop;
-    engine='sweep' surfaces it."""
+    """engine='auto' never serves a failing batched path with the scalar
+    loop: a run-time engine failure raises under 'auto' (no warning, no
+    scalar answer) exactly as under engine='sweep'."""
     import warnings as warnings_mod
     g = synth.cg_like(2, 2, 3, params=params)
     deltas = np.linspace(0.0, 20.0, 10)
-    ref = sensitivity.latency_curve(g, params, deltas, engine="scalar")
 
     def boom(self, *a, **k):
         raise RuntimeError("injected engine failure")
 
     monkeypatch.setattr(sweep.SweepEngine, "run", boom)
-    sweep_engine._WARNED.clear()       # the shared warn-once registry
-    with pytest.warns(RuntimeWarning, match="injected engine failure"):
-        auto = sensitivity.latency_curve(g, params, deltas)
-    np.testing.assert_allclose(auto.T, ref.T)
-    np.testing.assert_allclose(auto.lam, ref.lam)
-    # warned once: the second call falls back silently
     with warnings_mod.catch_warnings():
         warnings_mod.simplefilter("error", RuntimeWarning)
-        auto2 = sensitivity.latency_curve(g, params, deltas)
-    np.testing.assert_allclose(auto2.T, ref.T)
+        for _ in range(2):                       # every call, not just once
+            with pytest.raises(RuntimeError, match="injected engine failure"):
+                sensitivity.latency_curve(g, params, deltas)
     with pytest.raises(RuntimeError, match="injected"):
         sensitivity.latency_curve(g, params, deltas, engine="sweep")
 
@@ -584,53 +578,110 @@ def test_auto_dispatch_warns_once_then_falls_back(params, monkeypatch):
 def test_auto_dispatch_survives_engine_construction_failure(params,
                                                             monkeypatch):
     """Engine *construction* failures follow the same contract as run-time
-    ones: engine='auto' warns once and returns the scalar answer,
-    engine='sweep' surfaces the error (ImportError alone stays quiet)."""
+    ones: engine='auto' and engine='sweep' both surface the error."""
     g = synth.cg_like(2, 2, 3, params=params)
     deltas = np.linspace(0.0, 20.0, 10)
-    ref = sensitivity.latency_curve(g, params, deltas, engine="scalar")
 
     def boom(self, *a, **k):
         raise RuntimeError("injected construction failure")
 
     monkeypatch.setattr(sweep.SweepEngine, "__init__", boom)
-    sweep_engine._WARNED.clear()
-    with pytest.warns(RuntimeWarning, match="injected construction failure"):
-        auto = sensitivity.latency_curve(g, params, deltas)
-    np.testing.assert_allclose(auto.T, ref.T)
+    with pytest.raises(RuntimeError, match="injected construction failure"):
+        sensitivity.latency_curve(g, params, deltas)
     with pytest.raises(RuntimeError, match="injected construction"):
         sensitivity.latency_curve(g, params, deltas, engine="sweep")
 
 
+def test_auto_dispatch_scalar_only_without_jax(params, monkeypatch):
+    """The one quiet scalar path: JAX itself is absent
+    (``ModuleNotFoundError`` naming "jax").  Every dispatch site then
+    returns the scalar answer under engine='auto'."""
+    g = synth.cg_like(2, 2, 3, params=params)
+    deltas = np.linspace(0.0, 20.0, 10)
+    ref = sensitivity.latency_curve(g, params, deltas, engine="scalar")
+    tol_ref = sensitivity.latency_tolerance(g, params, (0.01, 0.02, 0.05,
+                                                        0.1), engine="scalar")
+
+    def no_jax(self, *a, **k):
+        raise ModuleNotFoundError("No module named 'jax'", name="jax")
+
+    monkeypatch.setattr(sweep.SweepEngine, "run", no_jax)
+    auto = sensitivity.latency_curve(g, params, deltas)
+    np.testing.assert_array_equal(auto.T, ref.T)
+    np.testing.assert_array_equal(auto.lam, ref.lam)
+    assert sensitivity.latency_tolerance(
+        g, params, (0.01, 0.02, 0.05, 0.1)) == tol_ref
+    with pytest.raises(ModuleNotFoundError):
+        sensitivity.latency_curve(g, params, deltas, engine="sweep")
+
+
+def test_auto_dispatch_raises_on_other_import_errors(params, monkeypatch):
+    """An ImportError that is not "JAX is missing" — an API that moved
+    inside an installed JAX, a sibling module that is absent — is a broken
+    device path and raises under engine='auto' (sensitivity and placement
+    alike)."""
+    from repro.core import placement
+    from repro.core.graph import GraphBuilder
+    from repro.core.loggps import LogGPS
+    g = synth.cg_like(2, 2, 3, params=params)
+    deltas = np.linspace(0.0, 20.0, 10)
+    errors = [ImportError("cannot import name 'enable_x64' from "
+                          "'jax.experimental'"),
+              ModuleNotFoundError("No module named 'jax.experimental.x'",
+                                  name="jax.experimental.x")]
+    for err in errors:
+        def broken(self, *a, _err=err, **k):
+            raise _err
+
+        monkeypatch.setattr(sweep.SweepEngine, "run", broken)
+        with pytest.raises(ImportError):
+            sensitivity.latency_curve(g, params, deltas)
+
+    zero = LogGPS(L=(0.0,), G=(0.0,), o=0.5, S=1e18)
+    b = GraphBuilder(4, 1)
+    for r in range(0, 4, 2):
+        b.add_calc(r, 1.0)
+        b.add_message(r, r + 1, 65536.0, zero)
+        b.add_message(r + 1, r, 65536.0, zero)
+    gz = b.finalize()
+    phi = placement.ArchTopology.two_tier(4, 2, L_fast=1.0, L_slow=20.0,
+                                          G_fast=1e-5, G_slow=4e-5)
+
+    def broken_run(self, *a, **k):
+        raise ImportError("cannot import name 'shard_map'")
+
+    from repro.sweep import api as sweep_api
+    monkeypatch.setattr(sweep_api.Engine, "run", broken_run)
+    stats: dict = {}
+    with pytest.raises(ImportError, match="shard_map"):
+        placement.place(gz, phi, params=zero,
+                        pi0=np.array([0, 2, 1, 3]), stats=stats)
+    assert stats["scalar_fallbacks"] == 0
+
+
 def test_pallas_lam_override_warns_once(params, monkeypatch):
-    """If the argmax kernel can't even be imported, an explicit
-    backend='pallas' λ request is overridden to segment WITH a one-time
-    warning — never silently."""
-    import warnings as warnings_mod
+    """If the argmax kernel can't be built, an explicit backend='pallas'
+    λ request raises — it is never re-routed to segment."""
     g = synth.stencil2d(2, 2, 2, params=params)
     eng = sweep.SweepEngine(g, params, cache=None)
     batch = sweep.latency_grid(params, [0.0, 5.0])
-    seg = eng.run(batch)
 
     real = sweep_engine._get_forward
 
-    def fake(kind, want_lam=False, multi=False, fused=False, mesh=None):
+    def fake(kind, want_lam=False, multi=False, fused=False, mesh=None,
+             **kw):
         if kind == "pallas" and want_lam:
             raise ImportError("no argmax kernel in this build")
-        return real(kind, want_lam, multi, fused, mesh)
+        return real(kind, want_lam, multi, fused, mesh, **kw)
 
     monkeypatch.setattr(sweep_engine, "_get_forward", fake)
-    sweep_engine._WARNED.clear()
-    with pytest.warns(RuntimeWarning, match="overriding to backend='segment'"):
-        res = eng.run(batch, backend="pallas", compute_lam=True)
-    assert res.backend == "segment"
-    np.testing.assert_array_equal(res.T, seg.T)
-    np.testing.assert_array_equal(res.lam, seg.lam)
-    with warnings_mod.catch_warnings():          # one-time: second is quiet
-        warnings_mod.simplefilter("error", RuntimeWarning)
-        res2 = eng.run(batch, backend="pallas", compute_lam=True,
-                       use_cache=False)
-    assert res2.backend == "segment"
+    for _ in range(2):
+        with pytest.raises(ImportError, match="no argmax kernel"):
+            eng.run(batch, backend="pallas", compute_lam=True,
+                    use_cache=False)
+    # the values-only pallas program is unaffected
+    res = eng.run(batch, backend="pallas", compute_lam=False)
+    assert res.backend == "pallas"
 
 
 def test_sensitivity_memo_key_is_content_based():
@@ -665,9 +716,9 @@ def test_sensitivity_memoizes_engine(params):
 
 def test_multisweep_override_warns_once_per_engine_instance(params,
                                                             monkeypatch):
-    """Regression: the MultiSweepEngine backend-override warning must fire
-    exactly once per engine INSTANCE — not once per run() call, and not
-    once per process (a fresh engine in a new study must warn again)."""
+    """Regression: a pallas λ request whose argmax kernel cannot be built
+    raises on every run of every engine instance (multi-graph and
+    single-graph) — no backend override, no warning."""
     import warnings as warnings_mod
     variants = sweep.collective_variants(
         lambda a: synth.allreduce_chain(8, 1, params=params, algo=a),
@@ -677,37 +728,24 @@ def test_multisweep_override_warns_once_per_engine_instance(params,
     real = sweep_engine._get_forward
 
     def fake(kind, want_lam=False, multi=False, fused=False, mesh=None,
-             costs=None):
+             **kw):
         if kind == "pallas" and want_lam:
             raise ImportError("no argmax kernel in this build")
-        return real(kind, want_lam, multi, fused, mesh, costs)
+        return real(kind, want_lam, multi, fused, mesh, **kw)
 
     monkeypatch.setattr(sweep_engine, "_get_forward", fake)
-    meng = sweep.MultiSweepEngine.from_variants(variants, cache=None)
-    with pytest.warns(RuntimeWarning, match="overriding to backend='segment'"):
-        r1 = meng.run(grid, backend="pallas", compute_lam=True)
-    assert r1.backend == "segment"
-    # second run on the SAME engine: quiet
-    with warnings_mod.catch_warnings():
-        warnings_mod.simplefilter("error", RuntimeWarning)
-        r2 = meng.run(grid, backend="pallas", compute_lam=True,
-                      use_cache=False)
-    assert r2.backend == "segment"
-    # a FRESH engine instance warns again (per-instance, not per-process)
-    meng2 = sweep.MultiSweepEngine.from_variants(variants, cache=None)
-    with pytest.warns(RuntimeWarning, match="overriding to backend='segment'"):
-        meng2.run(grid, backend="pallas", compute_lam=True, use_cache=False)
-    # same contract on the single-graph engine
     g = synth.stencil2d(2, 2, 2, params=params)
-    eng = sweep.SweepEngine(g, params, cache=None)
-    with pytest.warns(RuntimeWarning, match="overriding"):
-        eng.run(grid, backend="pallas", compute_lam=True)
     with warnings_mod.catch_warnings():
         warnings_mod.simplefilter("error", RuntimeWarning)
-        eng.run(grid, backend="pallas", compute_lam=True, use_cache=False)
-    eng2 = sweep.SweepEngine(g, params, cache=None)
-    with pytest.warns(RuntimeWarning, match="overriding"):
-        eng2.run(grid, backend="pallas", compute_lam=True)
+        for make in (lambda: sweep.MultiSweepEngine.from_variants(
+                         variants, cache=None),
+                     lambda: sweep.SweepEngine(g, params, cache=None)):
+            for _ in range(2):                   # fresh instance, twice each
+                eng = make()
+                for _ in range(2):
+                    with pytest.raises(ImportError, match="no argmax"):
+                        eng.run(grid, backend="pallas", compute_lam=True,
+                                use_cache=False)
 
 
 def test_cache_patched_cost_stats_and_eviction(params):

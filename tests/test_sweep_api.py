@@ -313,37 +313,33 @@ def test_argbest_rejects_bare_graph_axis(params):
 
 
 def test_pinned_dtype_refuses_pallas_lambda_fallback(params, monkeypatch):
-    """A policy that PINS dtype='float32' must never be silently served by
-    the float64 segment fallback when the argmax kernel is unavailable."""
+    """A pallas λ query whose argmax kernel is unavailable raises — pinned
+    dtype='float32' or not; it is never served by the float64 segment
+    backend."""
     g = synth.stencil2d(2, 2, 2, params=params)
     batch = sweep.latency_grid(params, [0.0, 5.0])
 
     real = sweep_engine._get_forward
 
     def fake(kind, want_lam=False, multi=False, fused=False, mesh=None,
-             costs=None):
+             **kw):
         if kind == "pallas" and want_lam:
             raise ImportError("no argmax kernel in this build")
-        return real(kind, want_lam, multi, fused, mesh, costs)
+        return real(kind, want_lam, multi, fused, mesh, **kw)
 
     monkeypatch.setattr(sweep_engine, "_get_forward", fake)
-    pinned = Engine(g, params=params,
-                    policy=ExecPolicy(backend="pallas", dtype="float32",
-                                      cache=None))
-    with pytest.raises(ImportError, match="pins the pallas float32"):
-        pinned.run(batch)
-    # unpinned: the documented warn-once override still applies
-    loose = Engine(g, params=params,
-                   policy=ExecPolicy(backend="pallas", cache=None))
-    with pytest.warns(RuntimeWarning, match="overriding to backend"):
-        res = loose.run(batch)
-    assert res.backend == "segment"
+    for dtype in ("float32", "auto"):
+        eng = Engine(g, params=params,
+                     policy=ExecPolicy(backend="pallas", dtype=dtype,
+                                       cache=None))
+        with pytest.raises(ImportError, match="no argmax kernel"):
+            eng.run(batch)
 
 
 def test_explicit_policy_failures_surface(params, monkeypatch):
-    """An explicit policy= is an explicit ask for the batched path: engine
-    failures must raise (like engine='sweep'), never silently fall back to
-    a scalar loop that ignores the policy's contract."""
+    """Engine failures raise whether or not a policy= was given: an
+    explicit policy pins the batched path, and the default 'auto' path
+    never hides a failure behind a scalar loop either."""
     from repro.sweep import api as sweep_api
 
     g = synth.cg_like(2, 2, 2, params=params)   # fresh graph: empty memo
@@ -355,8 +351,6 @@ def test_explicit_policy_failures_surface(params, monkeypatch):
     with pytest.raises(RuntimeError, match="injected unified-engine"):
         sensitivity.latency_curve(g, params, [0.1, 2.3],
                                   policy=ExecPolicy(cache=None))
-    # default path (no policy) keeps the documented warn-once fallback
-    sweep_engine._WARNED.clear()
-    with pytest.warns(RuntimeWarning, match="injected|falling back"):
+    with pytest.raises(RuntimeError, match="injected unified-engine"):
         # the shim delegates to Engine.run, so the boom hits 'auto' too
         sensitivity.latency_curve(g, params, np.linspace(0, 20, 10))
