@@ -14,7 +14,8 @@ Design constraints, in order:
    kwargs dict, no clock read, no lock.  Instrumentation can therefore
    live permanently on the hot path (``sweep/api.py``'s ``Engine.run``).
 2. **Cheap when enabled.**  A span is two ``perf_counter_ns`` reads and
-   one deque append under a lock; nesting comes from a thread-local name
+   one deque append under a lock (plus a profiler annotation while a JAX
+   profiler session records); nesting comes from a thread-local name
    stack (events record their parent), not from object graphs.
 3. **Exportable.**  ``to_chrome_trace()`` / ``export(path)`` emit the
    Chrome trace-event JSON that Perfetto (https://ui.perfetto.dev) and
@@ -33,6 +34,17 @@ Two recording scopes compose:
 Trace ids (``trace_context()``) stamp every span finished on the thread
 with a request-scoped id, so one Perfetto file of a busy service still
 separates interleaved requests.
+
+A live span also carries attributes its code learns while it runs
+(``with span(...) as sp: ... sp.set(levels=n)``): counts and phase times
+of the work the span itself does, recorded in its ``args`` rather than as
+child spans, so they never cut its self time.  And while a JAX profiler
+session records (``jax.profiler.start_trace``), a live span is mirrored
+into it as a ``jax.profiler.TraceAnnotation`` of the same name: a
+TensorBoard or Perfetto profile then shows the program's phases on the
+host plane, on the device ops' clock.  Each mirrored child span adds the
+annotation's cost to its parent's self time.  This module never imports
+JAX itself; it looks for it only where the process already loaded it.
 """
 
 from __future__ import annotations
@@ -41,6 +53,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -83,17 +96,25 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "t0_ns")
+    __slots__ = ("_tracer", "name", "args", "t0_ns", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+
+    def set(self, **attrs) -> None:
+        """Merge ``attrs`` into this span's ``args`` (its event's attributes
+        in ``SpanEvent.args`` and the Chrome export)."""
+        self.args.update(attrs)
 
     def __enter__(self):
         tls = self._tracer._tls
@@ -101,11 +122,21 @@ class _Span:
         if stack is None:
             stack = tls.stack = []
         stack.append(self.name)
+        # the profiler mirror, only while a profiler session records.  It
+        # is entered before this span's clock read and left after it, so
+        # its cost lands in the parent span's self time, not in this span's
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._annotation = None
+        if profiler is not None and profiler.TraceAnnotation.is_enabled():
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         tr = self._tracer
         stack = tr._tls.stack
         stack.pop()
@@ -153,7 +184,8 @@ class Tracer:
     def add_event(self, name: str, t0_ns: int, t1_ns: int, **args) -> None:
         """Record a span retrospectively from explicit clock stamps — for
         phases detected only after the fact (e.g. an XLA compile attributed
-        to a dispatch once the program count is seen to have grown)."""
+        to a dispatch once the program count is seen to have grown).  Such
+        an event is not mirrored into the profiler: its time is past."""
         if not self._enabled and getattr(self._tls, "sinks", None) is None:
             return
         self._record(SpanEvent(
@@ -199,9 +231,6 @@ class Tracer:
             yield tid
         finally:
             self._tls.trace = prev
-
-    def current_trace(self) -> Optional[str]:
-        return getattr(self._tls, "trace", None)
 
     # -- export --------------------------------------------------------------
     def events(self) -> list:

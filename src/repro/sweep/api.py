@@ -44,6 +44,7 @@ by ``tests/test_conformance.py``); ``core.sensitivity``,
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import os
@@ -1216,9 +1217,9 @@ class Engine:
             fwd_kw["shard_axis"] = axis
 
         # watcher bracketing: any growth in the XLA program count across
-        # this dispatch is attributed to this query's signature (the
-        # np.asarray transfers inside the span block on jax's async
-        # dispatch, so the window covers compile + execute)
+        # this dispatch is attributed to this query's signature (the span
+        # waits for jax's async dispatch, so the window covers compile +
+        # execute)
         axes_s = ("G" if has_G else "") + ("B" if has_B else "") \
             + ("K" if has_K else "") + "S"
         if sparse:
@@ -1229,9 +1230,14 @@ class Engine:
         n_prog0 = _WATCHER.programs()
         t0_ns = time.perf_counter_ns()
         t0 = time.perf_counter()
-        with _span("sweep.execute", backend=kind, axes=axes_s):
-            if sparse:
-                with _eng._jax().enable_x64():
+        with _span("sweep.execute", backend=kind, axes=axes_s) as ex:
+            # phases, recorded on the span (not as child spans, which would
+            # cut its self time): staging the inputs, the forward call, the
+            # wait for the device, the copies back to the host
+            t_stage = time.perf_counter_ns()
+            with (_eng._jax().enable_x64() if sparse or seg
+                  else contextlib.nullcontext()):
+                if sparse:
                     arrs = self._arrays("sparse")
                     # dtype="float32" pins the Pallas slot-list kernel
                     # flavor; float64 (native) is the bit-exact jnp
@@ -1242,13 +1248,8 @@ class Engine:
                     fwd = _eng._get_forward(
                         flavor, want_lam_compiled,
                         sparse_dims=(sp.Emax_lv, sp.Vmax_lv))
-                    T, lam = fwd(*arrs, jnp.asarray(Lmat),
-                                 jnp.asarray(GSmat))
-                    ran = _ran_on(T)
-                    T = np.asarray(T).astype(np.float64)
-                    lam = np.asarray(lam).astype(np.float64)
-            elif seg:
-                with _eng._jax().enable_x64():
+                    args = arrs + (jnp.asarray(Lmat), jnp.asarray(GSmat))
+                elif seg:
                     arrs = self._arrays("congestion" if cong else "segment")
                     if has_K:
                         cost_arrs = stage_costs(arrs)
@@ -1261,46 +1262,68 @@ class Engine:
                         pp = self.params
                         fwd = _eng._get_forward(
                             "congestion", want_lam_compiled, costs=kaxes)
-                        with _span("sweep.congestion_fixed_point",
-                                   max_iters=int(pol.max_iters)):
-                            T, lam, iters = fwd(
-                                *args,
-                                jnp.asarray(np.asarray(pp.alpha_full,
-                                                       dtype=np.float64)),
-                                jnp.asarray(np.asarray(pp.beta_full,
-                                                       dtype=np.float64)),
-                                jnp.asarray(np.int32(pol.max_iters)),
-                                jnp.asarray(np.float64(pol.tol)),
-                                jnp.asarray(Lmat), jnp.asarray(GSmat))
-                        iters = np.asarray(iters)
+                        args = args + (
+                            jnp.asarray(np.asarray(pp.alpha_full,
+                                                   dtype=np.float64)),
+                            jnp.asarray(np.asarray(pp.beta_full,
+                                                   dtype=np.float64)),
+                            jnp.asarray(np.int32(pol.max_iters)),
+                            jnp.asarray(np.float64(pol.tol)))
                     else:
                         fwd = _eng._get_forward(
                             "segment", want_lam_compiled, has_G, False,
                             mesh, **fwd_kw)
-                        T, lam = fwd(*args, jnp.asarray(Lmat),
-                                     jnp.asarray(GSmat))
-                    ran = _ran_on(T)
+                    args = args + (jnp.asarray(Lmat), jnp.asarray(GSmat))
+                else:
+                    arrs = self._arrays("pallas")
+                    if has_K:
+                        cost_arrs = stage_costs(arrs)
+                        args = arrs[:3] + cost_arrs + arrs[7:]
+                    else:
+                        args = arrs
+                    if has_B:
+                        args = stage_structure(args)
+                    fwd = _eng._get_forward("pallas", want_lam_compiled,
+                                            has_G, False, mesh, **fwd_kw)
+                    args = args + (jnp.asarray(Lmat, dtype=jnp.float32),
+                                   jnp.asarray(GSmat, dtype=jnp.float32))
+                t_call = time.perf_counter_ns()
+                with (_span("sweep.congestion_fixed_point",
+                            max_iters=int(pol.max_iters)) if cong
+                      else contextlib.nullcontext()):
+                    out = fwd(*args)
+                # the copies to the host queue behind the forward, so the
+                # wait below puts no round trip before them.  The per-call
+                # inputs are freed once the call returns and the outputs
+                # once copied, inside this span: their frees are the
+                # forward's host time, not the caller's
+                for a in out:
+                    a.copy_to_host_async()
+                del args
+                t_ret = time.perf_counter_ns()
+                _eng._jax().block_until_ready(out)
+                t_ready = time.perf_counter_ns()
+                T, lam = out[:2]
+                ran = _ran_on(T)
+                if cong:
+                    iters = np.asarray(out[2])
+                del out
+                if seg:
                     T = np.asarray(T)
                     lam = np.asarray(lam)
-            else:
-                arrs = self._arrays("pallas")
-                if has_K:
-                    cost_arrs = stage_costs(arrs)
-                    args = arrs[:3] + cost_arrs + arrs[7:]
                 else:
-                    args = arrs
-                if has_B:
-                    args = stage_structure(args)
-                fwd = _eng._get_forward("pallas", want_lam_compiled,
-                                        has_G, False, mesh, **fwd_kw)
-                T, lam = fwd(*args, jnp.asarray(Lmat, dtype=jnp.float32),
-                             jnp.asarray(GSmat, dtype=jnp.float32))
-                ran = _ran_on(T)
-                T = np.asarray(T).astype(np.float64)
-                lam = np.asarray(lam).astype(np.float64)
-                if has_G and has_K:               # [K, G, ...] → [G, K, ...]
-                    T = T.swapaxes(0, 1)
+                    T = np.asarray(T).astype(np.float64)
+                    lam = np.asarray(lam).astype(np.float64)
+                if not (sparse or seg) and has_G and has_K:
+                    T = T.swapaxes(0, 1)          # [K, G, ...] → [G, K, ...]
                     lam = lam.swapaxes(0, 1)
+                t_done = time.perf_counter_ns()
+            # levels: the graph's (the longest graph's in a batch), the
+            # work, not the loop's bucketed trip count
+            ex.set(stage_ns=t_call - t_stage, dispatch_ns=t_ret - t_call,
+                   wait_ns=t_ready - t_ret, readback_ns=t_done - t_ready,
+                   levels=(int(plan0.nlevels.max()) if has_G
+                           else plan0.nlevels))
         _WATCHER.attribute(
             n_prog0, time.perf_counter() - t0, t0_ns=t0_ns,
             backend=kind, axes=axes_s,

@@ -2,6 +2,9 @@
 thread-safety regression for the shared SweepCache."""
 
 import json
+import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -75,7 +78,10 @@ def test_trace_context_stamps_events():
         with tr.span("b"):
             pass
     assert spans2[0].trace == tid
-    assert tr.current_trace() is None
+    with tr.collect() as spans3:          # the id is gone after its scope
+        with tr.span("c"):
+            pass
+    assert spans3[0].trace is None
 
 
 def test_chrome_trace_export(tmp_path):
@@ -91,6 +97,41 @@ def test_chrome_trace_export(tmp_path):
     assert ev["ph"] == "X" and ev["name"] == "phase"
     assert ev["dur"] >= 0 and "ts" in ev and "pid" in ev and "tid" in ev
     assert ev["args"]["trace"] == "t-1" and ev["args"]["size"] == 3
+
+
+def test_set_on_a_live_span_lands_in_its_event_and_export():
+    tr = Tracer()
+    tr.enable()
+    with tr.span("phase", size=3) as sp:
+        sp.set(levels=7, done=True)
+        sp.set(levels=8)                  # later values win
+    with tr.span("bare") as sp:           # a span opened without args
+        sp.set(count=4)
+    phase, bare = tr.events()
+    assert phase.args == {"size": 3, "levels": 8, "done": True}
+    assert bare.args == {"count": 4}
+    recs = {r["name"]: r for r in tr.to_chrome_trace()["traceEvents"]}
+    assert recs["phase"]["args"]["levels"] == 8
+    assert recs["phase"]["args"]["done"] is True
+    assert recs["bare"]["args"] == {"count": 4}
+
+
+def test_set_on_the_disabled_span_is_a_noop_on_the_shared_object():
+    tr = Tracer()
+    with tr.span("a") as sp:
+        sp.set(levels=3, wait_ns=10)
+    assert sp is obs_trace._NOOP          # still the one shared object,
+    assert not hasattr(sp, "__dict__")    # which has nowhere to keep them
+    assert tr.events() == []
+
+
+def test_importing_obs_loads_no_jax():
+    # the profiler mirror looks JAX up only where the process loaded it
+    code = ("import sys, repro.obs; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=60).returncode == 0
 
 
 def test_summarize_aggregates_by_name():
@@ -267,15 +308,19 @@ def test_sweep_cache_metrics_flow_to_registry():
 jax = pytest.importorskip("jax")
 
 
+def _stencil(p):
+    from repro.core import synth
+    # a distinctive shape (odd iters) so this module's programs are its own
+    return synth.stencil2d(5, 4, 11, params=p)
+
+
 @pytest.fixture(scope="module")
 def warm_engine():
     from repro import sweep
-    from repro.core import synth
     from repro.core.loggps import cluster_params
 
     p = cluster_params(L_us=3.0, o_us=5.0)
-    # a distinctive shape (odd iters) so this module's programs are its own
-    g = synth.stencil2d(5, 4, 11, params=p)
+    g = _stencil(p)
     eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
     grid = sweep.latency_grid(p, np.linspace(0.0, 40.0, 7))
     eng.run(grid)                         # compile before the tests measure
@@ -374,3 +419,70 @@ def test_compile_events_carry_query_signature(warm_engine):
     assert sig["backend"] == "segment" and sig["axes"] == "S"
     assert "envelope" in sig and "S" in sig
     assert evs[-1].new_programs >= 1 and evs[-1].wall_s > 0.0
+
+
+@pytest.mark.parametrize("backend", ["segment", "sparse"])
+def test_execute_span_records_its_phases_and_work(backend, warm_engine):
+    from repro import sweep
+    _, grid, p = warm_engine
+    g = _stencil(p)                  # the fixture's shape: warm programs
+    eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(
+        cache=None, backend=backend))
+    with obs.collect() as spans:
+        eng.run(grid)
+    (ex,) = [e for e in spans if e.name == "sweep.execute"]
+    phases = [ex.args[k] for k in ("stage_ns", "dispatch_ns", "wait_ns",
+                                   "readback_ns")]
+    assert set(ex.args) == {"backend", "axes", "stage_ns", "dispatch_ns",
+                            "wait_ns", "readback_ns", "levels"}
+    assert all(isinstance(v, int) and v >= 0 for v in phases)
+    assert sum(phases) <= ex.t1_ns - ex.t0_ns
+    # the graph's own level count, not the loop's bucketed trip count
+    trips = (eng._sparse_plan().level_ptr.shape[0] - 1
+             if backend == "sparse" else eng.plan.vsrc.shape[0])
+    assert ex.args["levels"] == g.nlevels < trips
+    # phases are attributes, never child spans of sweep.execute
+    assert not [e for e in spans if e.parent == "sweep.execute"]
+
+
+def test_a_span_is_mirrored_only_while_the_profiler_records(tmp_path):
+    tr = Tracer()
+    tr.enable()
+    with tr.span("before") as sp:         # JAX loaded, no session
+        assert sp._annotation is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("during") as sp:
+            assert sp._annotation is not None
+    finally:
+        jax.profiler.stop_trace()
+    assert [e.name for e in tr.events()] == ["before", "during"]
+
+
+def test_spans_land_on_the_profiler_host_plane(tmp_path, warm_engine):
+    from jax.profiler import ProfileData
+    eng, grid, p = warm_engine
+    with obs.collect() as spans:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("test.request"):
+                eng.run(grid)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                host.setdefault(e.name, []).append(
+                    (plane.name, line.name, e.start_ns,
+                     e.start_ns + e.duration_ns))
+    assert {e.name for e in spans} <= set(host)
+    (ex,) = host["sweep.execute"]
+    (req,) = host["test.request"]
+    assert ex[:2] == req[:2]              # one thread's line of the host
+    assert req[2] <= ex[2] and ex[3] <= req[3]
+    (ev,) = [e for e in spans if e.name == "sweep.execute"]
+    assert ev.parent == "test.request"
