@@ -67,8 +67,9 @@ def forward_cell(kind: str, want_lam: bool = False, multi: bool = False,
     """The jitted forward for one engine cell (building it if needed) —
     for watchers scoped to a single program family, e.g. "did fd λ build
     a λ-backtrace program?".  ``structure`` (per-staged-arg vmap axes) and
-    ``sparse_dims`` ((Emax_lv, Vmax_lv) window sizes) select the
-    structure-batched and sparse cells."""
+    ``sparse_dims`` ((Emax_lv, Vmax_lv) window sizes, plus ``Dmax`` for
+    the in-edge-view step) select the structure-batched and sparse
+    cells."""
     from repro.sweep import engine as _eng
     kw = {}
     if structure is not None:

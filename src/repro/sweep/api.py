@@ -686,7 +686,7 @@ class Engine:
 
     def _arrays(self, kind: str) -> tuple:
         if kind not in self._dev:
-            if kind == "sparse":
+            if kind in ("sparse", "indeg"):
                 sp = self._sparse_plan()
                 self._dev[kind] = _eng._stage_arrays(
                     sp, kind, self.MAX_DENSE_BYTES)
@@ -1090,6 +1090,11 @@ class Engine:
             vf = sp.valid if sparse else plan0.valid_flat
             self._occupancy = float(np.count_nonzero(vf) / vf.size)
         _OCCUPANCY.set(self._occupancy, axis="slots")
+        # the float64 sparse forward's level step (compile.SparsePlan.step)
+        step = (sp.step if sparse and pol.dtype != "float32" else None)
+        if step == "indeg":
+            _OCCUPANCY.set(sp.ne / (sp.nlevels * sp.Vmax_lv * sp.Dmax),
+                           axis="indeg")
         _OCCUPANCY.set(Sext / Sp, axis="S")
         if has_K:
             _OCCUPANCY.set(K / Kp, axis="K")
@@ -1245,9 +1250,12 @@ class Engine:
                     # casts at the (max,+) reduction boundary.
                     flavor = ("sparse_pallas" if pol.dtype == "float32"
                               else "sparse")
-                    fwd = _eng._get_forward(
-                        flavor, want_lam_compiled,
-                        sparse_dims=(sp.Emax_lv, sp.Vmax_lv))
+                    dims = (sp.Emax_lv, sp.Vmax_lv)
+                    if step == "indeg":
+                        arrs = arrs + self._arrays("indeg")
+                        dims = dims + (sp.Dmax,)
+                    fwd = _eng._get_forward(flavor, want_lam_compiled,
+                                            sparse_dims=dims)
                     args = arrs + (jnp.asarray(Lmat), jnp.asarray(GSmat))
                 elif seg:
                     arrs = self._arrays("congestion" if cong else "segment")
@@ -1323,7 +1331,8 @@ class Engine:
             ex.set(stage_ns=t_call - t_stage, dispatch_ns=t_ret - t_call,
                    wait_ns=t_ready - t_ret, readback_ns=t_done - t_ready,
                    levels=(int(plan0.nlevels.max()) if has_G
-                           else plan0.nlevels))
+                           else plan0.nlevels),
+                   **({"step": step} if step else {}))
         _WATCHER.attribute(
             n_prog0, time.perf_counter() - t0, t0_ns=t0_ns,
             backend=kind, axes=axes_s,

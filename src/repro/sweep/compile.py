@@ -1090,8 +1090,15 @@ class SparsePlan:
     level, destination, original id) exactly like :func:`compile_plan`.
     ``level_ptr``/``v_ptr`` delimit each level's edge and vertex runs, and
     the forward walks levels with fixed ``[Emax_lv]``/``[Vmax_lv]``
-    windows (bucketed per-level maxima) via dynamic slices + segment-max —
-    memory is O(nv + ne), not O(nlv·Vmax·max(Dmax, Emax)).
+    windows (bucketed per-level maxima) via dynamic slices — memory is
+    O(nv + ne), not O(nlv·Vmax·max(Dmax, Emax)).
+
+    Each destination slot's in-edges form one run of that order:
+    ``vin0``/``vdeg`` give its start and length, and ``Dmax`` (the
+    bucketed maximum in-degree) sizes the scenario-shared
+    ``[Dmax, Vmax_lv]`` in-edge view the forward reduces over wherever
+    :attr:`step` is ``"indeg"``; elsewhere it runs a ``segment_max`` over
+    the edge window.
 
     Padding invariants the sparse forward relies on:
 
@@ -1122,6 +1129,9 @@ class SparsePlan:
     nlevels: int
     Emax_lv: int            # bucketed max edges in one level (window size)
     Vmax_lv: int            # bucketed max vertices in one level
+    vin0: np.ndarray        # [nv_p] int32 position of the slot's 1st in-edge
+    vdeg: np.ndarray        # [nv_p] int32 in-degree (pad → 0)
+    Dmax: int               # bucketed max in-degree (in-edge view width)
     # physical-link ids per edge (congestion carriage; pad → nlinks dummy)
     elink: Optional[np.ndarray] = None  # [ne_p] int32
     nlinks: int = 0
@@ -1132,14 +1142,26 @@ class SparsePlan:
         """Bucketed shapes + window sizes — equal keys share XLA programs."""
         return (self.esrc_slot.shape[0], self.vcost.shape[0],
                 self.level_ptr.shape[0], self.Emax_lv, self.Vmax_lv,
-                self.nclass)
+                self.Dmax, self.nclass)
+
+    @property
+    def step(self) -> str:
+        """The float64 forward's level step: ``"indeg"`` (the in-edge
+        view) where it pads at most twice the edge window, i.e.
+        ``Vmax_lv · Dmax ≤ 2 · Emax_lv``; else ``"segment"`` (a
+        ``segment_max`` over the window): a high-in-degree vertex, such
+        as a many-to-one gather's join, would pad the view far past the
+        edges it holds."""
+        return ("indeg" if self.Vmax_lv * self.Dmax <= 2 * self.Emax_lv
+                else "segment")
 
     def sparse_bytes(self) -> int:
         """Bytes the sparse backend stages for this plan."""
         return sum(getattr(self, n).nbytes for n in (
             "esrc_slot", "edst_slot", "emask", "econst", "egap", "egclass",
             "elat", "elat_sum", "vcost", "valid", "vert_of_slot",
-            "level_ptr", "v_ptr"))
+            "level_ptr", "v_ptr")
+            + (("vin0", "vdeg") if self.step == "indeg" else ()))
 
     def content_hash(self) -> str:
         h = getattr(self, "_hash", None)
@@ -1220,6 +1242,9 @@ def _assemble_sparse(nv: int, nc: int, nlevels: int,
         return out
 
     elat_p = padv(elat_s.astype(np.float64), ne_p, 0.0)
+    # edges sort by destination slot, so each slot's in-edges are one run
+    vdeg = np.bincount(edst_s, minlength=nv_p).astype(np.int32)
+    vin0 = (np.cumsum(vdeg) - vdeg).astype(np.int32)
     return SparsePlan(
         esrc_slot=padv(esrc_s, ne_p, 0, np.int32),
         edst_slot=padv(edst_s, ne_p, nv + Vmax_lv, np.int32),
@@ -1235,6 +1260,7 @@ def _assemble_sparse(nv: int, nc: int, nlevels: int,
         v_ptr=padv(v_ptr, nlv_p + 1, nv, np.int32),
         nv=nv, ne=ne, nclass=nc, nlevels=nlevels,
         Emax_lv=Emax_lv, Vmax_lv=Vmax_lv,
+        vin0=vin0, vdeg=vdeg, Dmax=_bucket(int(vdeg.max(initial=1)), lo=1),
         elink=(None if elink_s is None
                else padv(elink_s.astype(np.int32), ne_p, nlinks, np.int32)),
         nlinks=nlinks, link_classes=link_classes)

@@ -36,10 +36,16 @@ redispatch.  Float32 accumulators (like the TPU VPU): tolerance ≈ 1e-6
 relative vs segment.
 
 ``sparse``: compact CSR-style slot lists (``compile.SparsePlan``) — each
-level is a fixed-size window of the level-sorted edge list relaxed with a
-``segment_max`` over window-local destinations, so memory is O(nv + ne)
-with no dense padding at all.  Float64 with the same tie-break op
-sequences as ``segment`` (T and λ bit-identical); the scenario axis is
+level is a fixed-size window of the level-sorted edge list, so memory is
+O(nv + ne) with no dense padding at all.  Each destination's in-edges are
+one run of that list; where the plan's ``[Dmax, Vmax_lv]`` in-edge view
+pads at most twice the window (``SparsePlan.step == "indeg"``), the level
+max and the λ argmax are maxima and masked selects over that view,
+through window positions every scenario shares — no scatter and no
+per-scenario gather in the level loop.  A plan with a high-in-degree
+vertex (a many-to-one gather) keeps a ``segment_max`` over window-local
+destinations instead.  Float64 with the same tie-break comparisons as
+``segment`` (T and λ bit-identical) on either step; the scenario axis is
 ``vmap``'d and is the only batch axis.  :class:`repro.sweep.api.Engine`
 auto-switches to it when a graph's dense envelope would blow past
 ``MAX_DENSE_BYTES``.
@@ -739,39 +745,61 @@ def _dense_core_multi(want_lam: bool = False):
     return fwd
 
 
-def _make_sparse_one(want_lam: bool, Emax_lv: int, Vmax_lv: int):
+def _make_sparse_one(want_lam: bool, Emax_lv: int, Vmax_lv: int,
+                     Dmax: int = 0):
     """The single-scenario sparse (slot-list) forward.
 
     Levels are walked with fixed ``[Emax_lv]`` windows of the level-sorted
-    edge lists and ``[Vmax_lv]`` vertex windows; the level scatter-max is a
-    ``segment_max`` over window-local destinations (``edst − v_ptr[lv]``,
-    computed in-kernel).  :class:`~repro.sweep.compile.SparsePlan`'s
-    padding invariants make the windows safe: real levels never clamp,
-    padded levels' windows touch only pad slots, and pad/foreign edges
-    land at window-local destinations ≥ the destination level's true size
-    — overrun writes into later-level slots are overwritten by that
-    level's own full-window write before anything reads them, and
-    out-of-window destinations are dropped by scatter OOB semantics.
+    edge lists and ``[Vmax_lv]`` vertex windows.  Every candidate of a
+    level is computed over the edge window; :class:`~repro.sweep.compile.
+    SparsePlan`'s padding invariants make the windows safe: real levels
+    never clamp, padded levels' windows touch only pad slots, and
+    overrun writes into later-level slots are overwritten by that level's
+    own full-window write before anything reads them.
+
+    With ``Dmax`` (the plan's ``step == "indeg"``) the per-destination
+    reductions run over the **in-edge view**: slot ``v0 + i``'s in-edges
+    are the run ``vin0 + d``, ``d < vdeg``, so ``[Dmax, Vmax_lv]``
+    window-local positions index the window, the same for every scenario.
+    The level max, the max slope among value hits and the max in-edge
+    ordinal among the selected are maxima over ``d``, and the chosen
+    edge's source, slope sum and λ row are masked selects over ``d`` — no
+    scatter and no per-scenario gather in the level loop (under the
+    scenario ``vmap`` a gather through shared indices moves whole
+    scenario columns).  ``Dmax = 0`` keeps the ``segment_max`` step over
+    window-local destinations (``edst − v_ptr[lv]``, computed in-kernel;
+    pad/foreign edges land at destinations ≥ the level's true size, and
+    out-of-window ones are dropped by scatter OOB semantics): the plan
+    picks it where a high-in-degree vertex would pad the view past twice
+    the edge window.
 
     λ mirrors the segment backend's two-pass backtrace with the argmax in
     the *edge* domain: among value hits (within ATOL of the level max),
     max cumulative slope, then max global edge index — which, with edges
     sorted by (destination level, destination, original id), IS the max
-    in-edge ordinal.  Same float64 op sequences per path ⇒ T and λ are
-    bit-identical to ``segment``.
+    in-edge ordinal.  Both steps compare the same float64 values in the
+    same way and take maxima, which are exact ⇒ T and λ are bit-identical
+    to ``segment``.  The level loop runs under the ``sparse_level`` name
+    scope: a stable name for its ops in profiles and compiled HLO.
     """
     jax = _jax()
     jnp = jax.numpy
     dus = jax.lax.dynamic_update_slice
     dsl = jax.lax.dynamic_slice
+    view = Dmax > 0
 
     def one(esrc, edst, emask, econst, egap, egclass, elat, elat_sum,
-            vcost, valid, vert_of_slot, level_ptr, v_ptr, Lrow, gsrow):
+            vcost, valid, vert_of_slot, level_ptr, v_ptr, *rest):
+        if view:
+            vin0, vdeg, Lrow, gsrow = rest
+        else:
+            Lrow, gsrow = rest
         nlv = level_ptr.shape[0] - 1
         nv_p = vcost.shape[0]
         nc = elat.shape[1]
         eidx = jnp.arange(Emax_lv, dtype=jnp.int32)
         vidx = jnp.arange(Vmax_lv, dtype=jnp.int32)
+        didx = jnp.arange(Dmax, dtype=jnp.int32)
 
         def relax(lv, t):
             e0 = level_ptr[lv]
@@ -782,47 +810,95 @@ def _make_sparse_one(want_lam: bool, Emax_lv: int, Vmax_lv: int):
                  * (gsrow[dsl(egclass, (e0,), (Emax_lv,))] - 1.0)
                  + _dot(dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc)), Lrow))
             cand = jnp.where(em, t[es] + w, -BIG)
-            dloc = dsl(edst, (e0,), (Emax_lv,)) - v_ptr[lv]
-            seg = jax.ops.segment_max(cand, dloc, num_segments=Vmax_lv)
-            ts = jnp.maximum(seg, 0.0)
-            return e0, es, em, cand, dloc, ts
+            return e0, es, em, cand
+
+        if view:
+            def level_max(lv, e0, em, cand):
+                """The level max per slot over its in-edge view, and the
+                view: window-local positions (d-major, so slots stay the
+                minor axis), their validity (past vdeg, or outside the
+                window for overrun slots) and candidates."""
+                v0 = v_ptr[lv]
+                p = dsl(vin0, (v0,), (Vmax_lv,)) - e0 + didx[:, None]
+                inv = (didx[:, None] < dsl(vdeg, (v0,), (Vmax_lv,))) \
+                    & (p >= 0) & (p < Emax_lv)
+                p = jnp.clip(p, 0, Emax_lv - 1)
+                candv = jnp.where(inv, cand[p], -BIG)    # [Dmax, Vmax_lv]
+                return jnp.maximum(jnp.max(candv, axis=0), 0.0), \
+                    (p, inv, candv)
+
+            def choose(e0, es, em, cand, ts, ssum, vw):
+                """Chosen in-edge per slot: masked maxima and selects
+                over d, every index shared by scenarios.  Masked edges
+                carry −BIG, never within ATOL of ts ≥ 0."""
+                p, inv, candv = vw
+                hit = inv & (candv >= ts - ATOL)
+                esv = es[p]                              # [Dmax, Vmax_lv]
+                cs = ssum[esv] + dsl(elat_sum, (e0,), (Emax_lv,))[p]
+                best = jnp.max(jnp.where(hit, cs, -BIG), axis=0)
+                sel = hit & (cs >= best - ATOL)
+                dsel = jnp.max(jnp.where(sel, didx[:, None], -1), axis=0)
+                pick = didx[:, None] == dsel             # one d, or none
+                elat_v = dsl(elat, (e0, jnp.int32(0)), (Emax_lv, nc))[p]
+                return (dsel >= 0,
+                        jnp.max(jnp.where(pick, esv, -1), axis=0),
+                        jnp.max(jnp.where(pick, cs, -jnp.inf), axis=0),
+                        jnp.max(jnp.where(pick[:, :, None], elat_v,
+                                          -jnp.inf), axis=0))
+        else:
+            def level_max(lv, e0, em, cand):
+                """The level max per slot by ``segment_max`` over the
+                window's destinations, and those destinations."""
+                dloc = dsl(edst, (e0,), (Emax_lv,)) - v_ptr[lv]
+                seg = jax.ops.segment_max(cand, dloc, num_segments=Vmax_lv)
+                return jnp.maximum(seg, 0.0), dloc
+
+            def choose(e0, es, em, cand, ts, ssum, dloc):
+                """Chosen in-edge per slot by ``segment_max`` over the
+                window's destinations."""
+                dsafe = jnp.clip(dloc, 0, Vmax_lv - 1)
+                hit = em & (cand >= ts[dsafe] - ATOL)
+                cs = ssum[es] + dsl(elat_sum, (e0,), (Emax_lv,))
+                best = jax.ops.segment_max(jnp.where(hit, cs, -BIG), dloc,
+                                           num_segments=Vmax_lv)
+                sel = hit & (cs >= best[dsafe] - ATOL)
+                chosen = jax.ops.segment_max(
+                    jnp.where(sel, e0 + eidx, -1), dloc,
+                    num_segments=Vmax_lv)
+                has = chosen >= 0
+                ce = jnp.where(has, chosen, 0)
+                srcslot = esrc[ce]
+                return has, srcslot, ssum[srcslot] + elat_sum[ce], elat[ce]
 
         def vwin(lv):
             return dsl(vcost, (v_ptr[lv],), (Vmax_lv,))
 
         if not want_lam:
             def body(lv, t):
-                _, _, _, _, _, ts = relax(lv, t)
-                return dus(t, ts + vwin(lv), (v_ptr[lv],))
+                with jax.named_scope("sparse_level"):
+                    e0, _, em, cand = relax(lv, t)
+                    ts, _ = level_max(lv, e0, em, cand)
+                    return dus(t, ts + vwin(lv), (v_ptr[lv],))
 
             t = jax.lax.fori_loop(0, nlv, body, jnp.zeros(nv_p))
             T = jnp.max(jnp.where(valid, t, -BIG))
             return T, jnp.zeros((nc,))
 
         def body(lv, carry):
-            t, ssum, nxt, lrow = carry
-            e0, es, em, cand, dloc, ts = relax(lv, t)
-            dsafe = jnp.clip(dloc, 0, Vmax_lv - 1)
-            hit = em & (cand >= ts[dsafe] - ATOL)
-            cs = ssum[es] + dsl(elat_sum, (e0,), (Emax_lv,))
-            best = jax.ops.segment_max(jnp.where(hit, cs, -BIG), dloc,
-                                       num_segments=Vmax_lv)
-            sel = hit & (cs >= best[dsafe] - ATOL)
-            chosen = jax.ops.segment_max(
-                jnp.where(sel, e0 + eidx, -1), dloc,
-                num_segments=Vmax_lv)
-            has = chosen >= 0
-            ce = jnp.where(has, chosen, 0)
-            srcslot = esrc[ce]
-            ss_new = jnp.where(has, ssum[srcslot] + elat_sum[ce], 0.0)
-            own = v_ptr[lv] + vidx
-            nxt_row = jnp.where(has, srcslot, own).astype(jnp.int32)
-            row = jnp.where(has[:, None], elat[ce], 0.0)
-            v0 = v_ptr[lv]
-            return (dus(t, ts + vwin(lv), (v0,)),
-                    dus(ssum, ss_new, (v0,)),
-                    dus(nxt, nxt_row, (v0,)),
-                    dus(lrow, row, (v0, jnp.int32(0))))
+            with jax.named_scope("sparse_level"):
+                t, ssum, nxt, lrow = carry
+                e0, es, em, cand = relax(lv, t)
+                ts, aux = level_max(lv, e0, em, cand)
+                has, srcslot, ss, row = choose(e0, es, em, cand, ts, ssum,
+                                               aux)
+                own = v_ptr[lv] + vidx
+                nxt_row = jnp.where(has, srcslot, own).astype(jnp.int32)
+                v0 = v_ptr[lv]
+                return (dus(t, ts + vwin(lv), (v0,)),
+                        dus(ssum, jnp.where(has, ss, 0.0), (v0,)),
+                        dus(nxt, nxt_row, (v0,)),
+                        dus(lrow, jnp.where(has[:, None], row, 0.0),
+                            (v0, jnp.int32(0))))
 
         init = (jnp.zeros(nv_p), jnp.zeros(nv_p),
                 jnp.arange(nv_p, dtype=jnp.int32),
@@ -846,10 +922,13 @@ def _make_sparse_one(want_lam: bool, Emax_lv: int, Vmax_lv: int):
 def _sparse_core_axes(want_lam: bool, dims: tuple):
     """Sparse forward over S scenarios — the only batch axis the sparse
     backend populates (graphs past the dense cliff are evaluated solo).
-    ``dims`` = (Emax_lv, Vmax_lv), the static window sizes."""
+    ``dims`` = (Emax_lv, Vmax_lv), the static window sizes, plus ``Dmax``
+    for the in-edge-view step, which then takes ``vin0, vdeg`` after the
+    13 slot-list arrays."""
     jax = _jax()
     one = _make_sparse_one(want_lam, *dims)
-    return jax.vmap(one, in_axes=(None,) * 13 + (0, 0))
+    nplan = 13 + 2 * (len(dims) > 2)
+    return jax.vmap(one, in_axes=(None,) * nplan + (0, 0))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -1037,6 +1116,8 @@ def _stage_arrays(plan, kind: str, max_dense_bytes: int) -> tuple:
             plan.vsrc, plan.vmaskd, plan.vconst, plan.vgap, plan.vgclass,
             plan.vlat, plan.vlat_sum, plan.vcost_lv, plan.valid_flat,
             plan.vert_of_slot))
+    if kind == "indeg":
+        return (jnp.asarray(plan.vin0), jnp.asarray(plan.vdeg))
     if kind == "sparse":
         return tuple(jnp.asarray(a) for a in (
             plan.esrc_slot, plan.edst_slot, plan.emask, plan.econst,
@@ -1159,7 +1240,7 @@ def _get_forward(kind: str, want_lam: bool = False, multi: bool = False,
             raise ValueError("sparse backend does not shard yet")
         if sparse_dims is None:
             raise ValueError("sparse forward needs sparse_dims="
-                             "(Emax_lv, Vmax_lv)")
+                             "(Emax_lv, Vmax_lv[, Dmax])")
     if kind == "congestion":
         if multi or structure is not None:
             raise ValueError("the congestion fixed point populates the S "

@@ -433,8 +433,12 @@ def test_execute_span_records_its_phases_and_work(backend, warm_engine):
     (ex,) = [e for e in spans if e.name == "sweep.execute"]
     phases = [ex.args[k] for k in ("stage_ns", "dispatch_ns", "wait_ns",
                                    "readback_ns")]
+    # a float64 sparse dispatch also names its level step
     assert set(ex.args) == {"backend", "axes", "stage_ns", "dispatch_ns",
-                            "wait_ns", "readback_ns", "levels"}
+                            "wait_ns", "readback_ns", "levels"} | (
+                                {"step"} if backend == "sparse" else set())
+    if backend == "sparse":
+        assert ex.args["step"] == eng._sparse_plan().step
     assert all(isinstance(v, int) and v >= 0 for v in phases)
     assert sum(phases) <= ex.t1_ns - ex.t0_ns
     # the graph's own level count, not the loop's bucketed trip count

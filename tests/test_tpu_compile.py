@@ -123,3 +123,47 @@ def test_sparse_f32_forward_compiles_for_v5e(one_chip, monkeypatch):
                             lambda interpret=None: False)
         compiled = fwd.lower(*args, L, L).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _level_loop_ops(hlo: str) -> list:
+    """(opcode, index-operand dims) of every gather and scatter the
+    sparse forward's level loop (``sparse_level`` scope) compiled to."""
+    import re
+    dims = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+            for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]",
+                                 hlo, re.M)}
+    ops = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \S+ (gather|scatter)\(%[\w.\-]+, %([\w.\-]+)", line)
+        if m and "/sparse_level/" in line:
+            ops.append((m.group(1), dims.get(m.group(2))))
+    return ops
+
+
+def test_sparse_f64_level_loop_has_no_per_scenario_gather(one_chip,
+                                                          lulesh_graph):
+    """The float64 sparse λ forward on a LULESH plan takes the in-edge
+    view: its level loop gathers only through indices every scenario
+    shares (no index operand carries the scenario axis) and holds no
+    ``scatter-max``."""
+    from repro import sweep
+    from repro.sweep import engine as sweep_engine
+    g, p = lulesh_graph(2, 1)
+    sp = sweep.compile_sparse(g, p)
+    assert sp.step == "indeg"
+    S = 128
+    assert S not in (sp.Emax_lv, sp.Vmax_lv, sp.Dmax)
+    eng = sweep.Engine(sp, params=p, policy=sweep.ExecPolicy(cache=None,
+                                                             backend="sparse"))
+    with jax.enable_x64():
+        arrs = eng._arrays("sparse") + eng._arrays("indeg")
+        args = [jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+                for a in arrs]
+        L = jax.ShapeDtypeStruct((S, 1), jnp.float64, sharding=one_chip)
+        fwd = sweep_engine._get_forward(
+            "sparse", True, sparse_dims=(sp.Emax_lv, sp.Vmax_lv, sp.Dmax))
+        hlo = fwd.lower(*args, L, L).compile().as_text()
+    ops = _level_loop_ops(hlo)
+    assert any(op == "gather" for op, _ in ops)
+    assert not [d for op, d in ops if op == "scatter"]
+    assert not [d for op, d in ops if op == "gather" and S in d]
