@@ -1229,9 +1229,15 @@ class Engine:
             + ("K" if has_K else "") + "S"
         if sparse:
             env_s = f"ne{sp.esrc_slot.shape[0]}v{sp.vcost.shape[0]}"
+            trips = sp.level_ptr.shape[0] - 1
         else:
             nlv_p, Vmax, Dmax = plan0.vsrc.shape[-3:]
             env_s = f"{nlv_p}x{Vmax}x{Dmax}"
+            trips = nlv_p
+        # the forward that runs: on the sparse backend dtype="float32"
+        # pins the Pallas slot-list kernel, float64 the bit-exact jnp one
+        view = ("sparse_pallas" if sparse and pol.dtype == "float32"
+                else kind)
         n_prog0 = _WATCHER.programs()
         t0_ns = time.perf_counter_ns()
         t0 = time.perf_counter()
@@ -1244,17 +1250,13 @@ class Engine:
                   else contextlib.nullcontext()):
                 if sparse:
                     arrs = self._arrays("sparse")
-                    # dtype="float32" pins the Pallas slot-list kernel
-                    # flavor; float64 (native) is the bit-exact jnp
-                    # forward.  Same staged arrays — the kernel core
-                    # casts at the (max,+) reduction boundary.
-                    flavor = ("sparse_pallas" if pol.dtype == "float32"
-                              else "sparse")
+                    # both views take the same staged arrays — the kernel
+                    # core casts at the (max,+) reduction boundary
                     dims = (sp.Emax_lv, sp.Vmax_lv)
                     if step == "indeg":
                         arrs = arrs + self._arrays("indeg")
                         dims = dims + (sp.Dmax,)
-                    fwd = _eng._get_forward(flavor, want_lam_compiled,
+                    fwd = _eng._get_forward(view, want_lam_compiled,
                                             sparse_dims=dims)
                     args = arrs + (jnp.asarray(Lmat), jnp.asarray(GSmat))
                 elif seg:
@@ -1327,11 +1329,12 @@ class Engine:
                     lam = lam.swapaxes(0, 1)
                 t_done = time.perf_counter_ns()
             # levels: the graph's (the longest graph's in a batch), the
-            # work, not the loop's bucketed trip count
+            # work; trips: the loop's bucketed trip count, what it costs
             ex.set(stage_ns=t_call - t_stage, dispatch_ns=t_ret - t_call,
                    wait_ns=t_ready - t_ret, readback_ns=t_done - t_ready,
                    levels=(int(plan0.nlevels.max()) if has_G
                            else plan0.nlevels),
+                   view=view, trips=trips,
                    **({"step": step} if step else {}))
         _WATCHER.attribute(
             n_prog0, time.perf_counter() - t0, t0_ns=t0_ns,

@@ -421,13 +421,19 @@ def test_compile_events_carry_query_signature(warm_engine):
     assert evs[-1].new_programs >= 1 and evs[-1].wall_s > 0.0
 
 
-@pytest.mark.parametrize("backend", ["segment", "sparse"])
-def test_execute_span_records_its_phases_and_work(backend, warm_engine):
+#: the forward that runs (the span's ``view``) under each policy
+VIEWS = {"segment": {"backend": "segment"}, "pallas": {"backend": "pallas"},
+         "sparse": {"backend": "sparse"},
+         "sparse_pallas": {"backend": "sparse", "dtype": "float32"}}
+
+
+@pytest.mark.parametrize("view", list(VIEWS))
+def test_execute_span_records_its_phases_and_work(view, warm_engine):
     from repro import sweep
     _, grid, p = warm_engine
     g = _stencil(p)                  # the fixture's shape: warm programs
     eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(
-        cache=None, backend=backend))
+        cache=None, **VIEWS[view]))
     with obs.collect() as spans:
         eng.run(grid)
     (ex,) = [e for e in spans if e.name == "sweep.execute"]
@@ -435,16 +441,20 @@ def test_execute_span_records_its_phases_and_work(backend, warm_engine):
                                    "readback_ns")]
     # a float64 sparse dispatch also names its level step
     assert set(ex.args) == {"backend", "axes", "stage_ns", "dispatch_ns",
-                            "wait_ns", "readback_ns", "levels"} | (
-                                {"step"} if backend == "sparse" else set())
-    if backend == "sparse":
+                            "wait_ns", "readback_ns", "levels", "view",
+                            "trips"} | (
+                                {"step"} if view == "sparse" else set())
+    if view == "sparse":
         assert ex.args["step"] == eng._sparse_plan().step
+    assert ex.args["backend"] == VIEWS[view]["backend"]
+    assert ex.args["view"] == view
     assert all(isinstance(v, int) and v >= 0 for v in phases)
     assert sum(phases) <= ex.t1_ns - ex.t0_ns
-    # the graph's own level count, not the loop's bucketed trip count
+    # the graph's own level count, and the loop's bucketed trip count
     trips = (eng._sparse_plan().level_ptr.shape[0] - 1
-             if backend == "sparse" else eng.plan.vsrc.shape[0])
-    assert ex.args["levels"] == g.nlevels < trips
+             if view.startswith("sparse") else eng.plan.vsrc.shape[0])
+    assert ex.args["levels"] == g.nlevels < trips == ex.args["trips"]
+    assert isinstance(ex.args["trips"], int)
     # phases are attributes, never child spans of sweep.execute
     assert not [e for e in spans if e.parent == "sweep.execute"]
 

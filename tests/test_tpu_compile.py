@@ -125,6 +125,33 @@ def test_sparse_f32_forward_compiles_for_v5e(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_dense_f32_forward_compiles_for_v5e(one_chip, lulesh_graph,
+                                           monkeypatch):
+    """The dense Pallas λ forward at ``lulesh_64r``'s envelope (LULESH at
+    4³ ranks, 6 cycles: 1,024 trips of 64 vertices and 128 edges) and the
+    ``grid_f32`` traffic's 512 scenarios compiles for the TPU, kernel
+    included, and fits one chip."""
+    from repro import sweep
+    from repro.kernels.maxplus import ops
+    from repro.sweep import engine as sweep_engine
+    g, p = lulesh_graph(4, 6, 0.1, 1)
+    eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(
+        backend="pallas", cache=None))
+    args = [jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+            for a in eng._arrays("pallas")]
+    assert args[0].shape == (1024, 64, 128)
+    L = jax.ShapeDtypeStruct((512, 1), jnp.float32, sharding=one_chip)
+    # the forward resolves interpret mode from the (CPU) default backend;
+    # compile the kernel itself as on the chip
+    monkeypatch.setattr(ops, "resolve_interpret",
+                        lambda interpret=None: False)
+    compiled = sweep_engine._get_forward("pallas", True).lower(
+        *args, L, L).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1 << 30
+
+
 def _level_loop_ops(hlo: str) -> list:
     """(opcode, index-operand dims) of every gather and scatter the
     sparse forward's level loop (``sparse_level`` scope) compiled to."""
