@@ -137,7 +137,7 @@ def maxplus_matvec_kernel(A, t, *, bm: int = 128, bn: int = 128,
                                          interpret=interpret)[0]
 
 
-def _maxplus_argmax_kernel(A_ref, t_ref, c_ref, o_ref, i_ref,
+def _maxplus_argmax_kernel(A_ref, t_ref, c_ref, o_ref, i_ref, k_ref,
                            accv_ref, acck_ref, acci_ref,
                            *, n_n: int, bn: int, bj: int):
     jn = pl.program_id(3)
@@ -161,17 +161,21 @@ def _maxplus_argmax_kernel(A_ref, t_ref, c_ref, o_ref, i_ref,
 
     @pl.when(jn == n_n - 1)
     def _finish():
-        o_ref[0] = accv_ref[...].astype(o_ref.dtype)
+        v = accv_ref[...]
+        o_ref[0] = v.astype(o_ref.dtype)
         i_ref[0] = acci_ref[...]
+        # the −∞ sentinel chain carries no key
+        k_ref[0] = jnp.where(v > NEG_INF, acck_ref[...], NEG_INF)
 
 
 def maxplus_matvec_argmax_batched_kernel(A, t, c, *, bm: int = 128,
                                          bn: int = 128,
                                          interpret: bool = False):
     """Graph-batched argmax-emitting (max,+): A [G, M, N], t/c [G, N, K] →
-    (out [G, M, K], idx [G, M, K] int32).  Graphs ride the outermost grid
-    axis (as in :func:`maxplus_matvec_batched_kernel`); K (scenarios) rides
-    the 128-wide lane axis."""
+    (out [G, M, K], idx [G, M, K] int32, key [G, M, K] float32).  Graphs
+    ride the outermost grid axis (as in
+    :func:`maxplus_matvec_batched_kernel`); K (scenarios) rides the
+    128-wide lane axis."""
     G, M, N = A.shape
     K = t.shape[2]
     bm, bn, bk, bj = _blocks(M, N, K, bm, bn)
@@ -188,10 +192,12 @@ def maxplus_matvec_argmax_batched_kernel(A, t, c, *, bm: int = 128,
         out_specs=[
             pl.BlockSpec((1, bm, bk), lambda g, i, k, j: (g, i, k)),
             pl.BlockSpec((1, bm, bk), lambda g, i, k, j: (g, i, k)),
+            pl.BlockSpec((1, bm, bk), lambda g, i, k, j: (g, i, k)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, M, K), t.dtype),
             jax.ShapeDtypeStruct((G, M, K), jnp.int32),
+            jax.ShapeDtypeStruct((G, M, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32),
                         pltpu.VMEM((bm, bk), jnp.float32),
@@ -203,18 +209,22 @@ def maxplus_matvec_argmax_batched_kernel(A, t, c, *, bm: int = 128,
 
 def maxplus_matvec_argmax_kernel(A, t, c, *, bm: int = 128, bn: int = 128,
                                  interpret: bool = False):
-    """(max,+) mat-vec that also emits the realizing candidate's ordinal.
+    """(max,+) mat-vec that also emits the realizing candidate's ordinal
+    and tie key.
 
     A: [M, N] (−inf = no edge); t: [N, K] candidate values; c: [N, K]
-    tie keys → (out [M, K], idx [M, K] int32) where ``idx[i, k]`` is the
-    lexicographic argmax over j of ``(A[i,j]+t[j,k], c[j,k], j)`` — the λ
-    backtrace's "max cumulative slope, then max ordinal" rule among exact
-    value ties.  Rows with no finite candidate return idx of the −∞
-    sentinel chain (mask with ``out >= 0`` downstream).
+    tie keys → (out [M, K], idx [M, K] int32, key [M, K] float32) where
+    ``idx[i, k]`` is the lexicographic argmax over j of
+    ``(A[i,j]+t[j,k], c[j,k], j)`` — the λ backtrace's "max cumulative
+    slope, then max ordinal" rule among exact value ties — and
+    ``key[i, k] = c[idx[i, k], k]``, the winner's key itself, so a caller
+    that carries the key forward needs no gather through ``idx``.  Rows
+    with no finite candidate return idx of the −∞ sentinel chain and key
+    ``NEG_INF`` (mask both with ``out >= 0`` downstream).
     """
-    o, i = maxplus_matvec_argmax_batched_kernel(
+    o, i, k = maxplus_matvec_argmax_batched_kernel(
         A[None], t[None], c[None], bm=bm, bn=bn, interpret=interpret)
-    return o[0], i[0]
+    return o[0], i[0], k[0]
 
 
 def _maxplus_slotlist_argmax_kernel(d_ref, t_ref, c_ref, o_ref, i_ref,

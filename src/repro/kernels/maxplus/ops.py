@@ -52,9 +52,10 @@ def maxplus_matvec(A, t, *, bm: int = 128, bn: int = 128, interpret: bool = None
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def maxplus_matvec_argmax(A, t, c, *, bm: int = 128, bn: int = 128,
                           interpret: bool = None):
-    """(max,+) mat-vec emitting the realizing candidate ordinal: the λ
-    backtrace consumes the [M, K] int32 index plane (lexicographic argmax
-    of (value, tie key c, ordinal))."""
+    """(max,+) mat-vec emitting the realizing candidate's ordinal and tie
+    key: the λ backtrace consumes the [M, K] int32 index plane
+    (lexicographic argmax of (value, tie key c, ordinal)), the forward's
+    slope bookkeeping the [M, K] winning key."""
     with _x32():
         return maxplus_matvec_argmax_kernel(
             A, t, c, bm=bm, bn=bn, interpret=resolve_interpret(interpret))
@@ -75,7 +76,8 @@ def maxplus_slotlist_argmax(dst, cand, c, *, M: int, bm: int = 128,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def maxplus_matvec_argmax_batched(A, t, c, *, bm: int = 128, bn: int = 128,
                                   interpret: bool = None):
-    """[G, M, N] ⊗ [G, N, K] → ([G, M, K], [G, M, K] int32 argmax)."""
+    """[G, M, N] ⊗ [G, N, K] → ([G, M, K], [G, M, K] int32 argmax,
+    [G, M, K] winning tie key)."""
     with _x32():
         return maxplus_matvec_argmax_batched_kernel(
             A, t, c, bm=bm, bn=bn, interpret=resolve_interpret(interpret))

@@ -30,7 +30,9 @@ def maxplus_slotlist_argmax_ref(dst, cand, c, M: int):
 
 def maxplus_matvec_argmax_ref(A, t, c):
     """Oracle for the argmax-emitting kernel: lexicographic argmax over j of
-    (A[i,j]+t[j,k], c[j,k], j) with exact comparisons, plus the max value."""
+    (A[i,j]+t[j,k], c[j,k], j) with exact comparisons, plus the max value
+    and the winner's key (−1e30 where no candidate is above −1e30)."""
+    NEG_INF = -1e30
     cand = A[:, :, None] + t[None, :, :]             # [M, N, K]
     out = jnp.max(cand, axis=1)
     tie = cand >= out[:, None, :]
@@ -38,4 +40,5 @@ def maxplus_matvec_argmax_ref(A, t, c):
     tie &= c[None, :, :] >= bk[:, None, :]
     jidx = jnp.arange(A.shape[1], dtype=jnp.int32)[None, :, None]
     idx = jnp.max(jnp.where(tie, jidx, -1), axis=1)
-    return out, idx
+    key = jnp.where(out > NEG_INF, bk, NEG_INF).astype(jnp.float32)
+    return out, idx, key

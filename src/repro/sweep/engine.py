@@ -545,16 +545,19 @@ def _dense_core(want_lam: bool = False):
 
     Values-only runs the plain kernel; with ``want_lam`` the argmax-emitting
     kernel variant records each level's realizing edge slot (tie keys =
-    cumulative slope sums, mirroring the segment rule) and a reverse
-    backtrace scan over the recorded slots recovers λ — T/λ/ρ straight from
-    the pallas backend, no segment redispatch.  Float32 accumulators (TPU
-    VPU layout) → T matches segment to ~1e-6 relative.  Tie caveat: the
-    kernel compares candidates *exactly* (a tolerance-grouped tie set is
-    not associative across its blocked reduction), where segment groups
-    float64 candidates within ATOL — structurally tied paths still compare
-    equal in f32 (identical op sequences), but a pair of paths whose f64
-    sums tie only to within ATOL can resolve differently and shift λ by a
-    whole count; segment is the bit-exact reference when that matters.
+    cumulative slope sums, mirroring the segment rule) and returns the
+    winner's key, which is the new slope sum itself (no per-scenario
+    gather); a reverse backtrace scan over the recorded slots recovers λ —
+    T/λ/ρ straight from the pallas backend, no segment redispatch.  The λ
+    level loop runs under the ``dense_level`` name scope.  Float32
+    accumulators (TPU VPU layout) → T matches segment to ~1e-6 relative.
+    Tie caveat: the kernel compares candidates *exactly* (a
+    tolerance-grouped tie set is not associative across its blocked
+    reduction), where segment groups float64 candidates within ATOL —
+    structurally tied paths still compare equal in f32 (identical op
+    sequences), but a pair of paths whose f64 sums tie only to within ATOL
+    can resolve differently and shift λ by a whole count; segment is the
+    bit-exact reference when that matters.
     """
     jax = _jax()
     jnp = jax.numpy
@@ -591,23 +594,22 @@ def _dense_core(want_lam: bool = False):
             return T, jnp.zeros((S, nc), jnp.float32)
 
         def body(lv, carry):
-            t_end, ssum, chosen_all = carry
-            cand = edge_cand(lv, t_end)
-            cs = (ssum[esrc[lv]]
-                  + elat_sum[lv][:, None]).astype(jnp.float32)  # [Emax, S]
-            raw, eidx = maxplus_matvec_argmax(A[lv], cand, cs)  # [Vmax, S]
-            ts = jnp.maximum(raw, 0.0)
-            chosen = jnp.where(raw >= 0.0, eidx, -1)
-            e_s = jnp.where(chosen >= 0, chosen, 0)
-            src_slot = esrc[lv][e_s]                            # [Vmax, S]
-            gss = jnp.take_along_axis(ssum, src_slot, axis=0)
-            ss_new = jnp.where(chosen >= 0, gss + elat_sum[lv][e_s], 0.0)
-            off = lv * Vmax
-            return (jax.lax.dynamic_update_slice(
-                        t_end, ts + vcost_lv[lv][:, None], (off, 0)),
-                    jax.lax.dynamic_update_slice(ssum, ss_new, (off, 0)),
-                    jax.lax.dynamic_update_slice(chosen_all, chosen[None],
-                                                 (lv, 0, 0)))
+            with jax.named_scope("dense_level"):
+                t_end, ssum, chosen_all = carry
+                cand = edge_cand(lv, t_end)
+                cs = (ssum[esrc[lv]]
+                      + elat_sum[lv][:, None]).astype(jnp.float32)  # [Emax, S]
+                # key = cs at the winning edge: the chosen path's slope sum
+                raw, eidx, key = maxplus_matvec_argmax(A[lv], cand, cs)
+                ts = jnp.maximum(raw, 0.0)                      # [Vmax, S]
+                chosen = jnp.where(raw >= 0.0, eidx, -1)
+                ss_new = jnp.where(raw >= 0.0, key, 0.0)
+                off = lv * Vmax
+                return (jax.lax.dynamic_update_slice(
+                            t_end, ts + vcost_lv[lv][:, None], (off, 0)),
+                        jax.lax.dynamic_update_slice(ssum, ss_new, (off, 0)),
+                        jax.lax.dynamic_update_slice(chosen_all, chosen[None],
+                                                     (lv, 0, 0)))
 
         init = (jnp.zeros((nflat, S), jnp.float32),
                 jnp.zeros((nflat, S), jnp.float32),
@@ -692,16 +694,11 @@ def _dense_core_multi(want_lam: bool = False):
             cand = edge_cand(lv, t_end)
             cs = (jnp.take_along_axis(ssum, esrc[:, lv][:, :, None], axis=1)
                   + elat_sum[:, lv][:, :, None]).astype(jnp.float32)
-            raw, eidx = maxplus_matvec_argmax_batched(A[:, lv], cand, cs)
+            raw, eidx, key = maxplus_matvec_argmax_batched(A[:, lv], cand,
+                                                           cs)
             ts = jnp.maximum(raw, 0.0)                       # [G, Vmax, S]
             chosen = jnp.where(raw >= 0.0, eidx, -1)
-            e_s = jnp.where(chosen >= 0, chosen, 0)
-            src_slot = jnp.take_along_axis(esrc[:, lv][:, :, None], e_s,
-                                           axis=1)           # [G, Vmax, S]
-            gss = jnp.take_along_axis(ssum, src_slot, axis=1)
-            ces = jnp.take_along_axis(elat_sum[:, lv][:, :, None], e_s,
-                                      axis=1)
-            ss_new = jnp.where(chosen >= 0, gss + ces, 0.0)
+            ss_new = jnp.where(raw >= 0.0, key, 0.0)
             off = lv * Vmax
             return (jax.lax.dynamic_update_slice(
                         t_end, ts + vcost_lv[:, lv][:, :, None], (0, off, 0)),

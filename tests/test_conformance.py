@@ -524,6 +524,83 @@ def test_unified_axis_matrix(backend, axisset, unified_ref):
                   f"{c.name}/{axisset}")
 
 
+# -- dense pallas λ under exact ties ------------------------------------------
+
+#: every cost a small integer (µs; 1,025 B messages cost 1 µs of gap), so
+#: float32 and float64 sums are both exact
+INT_P = LogGPS(L=(3.0,), G=(2.0 ** -10,), o=5.0, S=1e9, class_names=("ib",))
+
+
+def _hop_tie(comp_us: float):
+    """Five ping-pong hops between ranks 0 and 1 race two equal single-hop
+    messages that ranks 2 and 3 send after ``comp_us`` of compute.  At the
+    L where the paths meet (a multiple of 1/4: the hop counts differ by 4)
+    the values tie, and only the slope sum carried from earlier levels
+    picks the five-hop path, as float64 segment does."""
+    from repro.core.graph import GraphBuilder
+    b = GraphBuilder(4, 1)
+    for i in range(5):
+        b.add_message(i % 2, 1 - i % 2, 1025.0, INT_P)
+    for r in (2, 3):
+        b.add_calc(r, comp_us)
+        b.add_message(r, 1, 1025.0, INT_P)
+    b.add_calc(1, 1.0)
+    return b.finalize()
+
+
+def _integer_tie_cases():
+    """Tie-heavy integer-cost graphs: a symmetric stencil, whose many
+    structurally equal paths tie in value and slope sum alike, and two
+    hop races, on absolute L from 0 to 64 µs in steps of 1/4."""
+    graphs = [("stencil", synth.stencil2d(3, 3, 4, halo_bytes=1025.0,
+                                          comp_us=500.0, params=INT_P)),
+              ("hops150", _hop_tie(150.0)), ("hops200", _hop_tie(200.0))]
+    batch = sweep.latency_grid(INT_P, np.arange(0.0, 64.0, 0.25),
+                               absolute=True)
+    rng = np.random.default_rng(5)
+    cases = []
+    for name, g in graphs:
+        extras = np.where(g.ebytes[None, :] > 0,
+                          rng.integers(0, 4, size=(K, g.num_edges)), 0)
+        cases.append(Case(name=name, g=g, params=INT_P, batch=batch,
+                          extras=extras.astype(np.float64)))
+    return cases
+
+
+@pytest.mark.parametrize("axisset", ("S", "GS", "KS"))
+def test_pallas_lam_exact_under_integer_ties(axisset):
+    """On integer-cost graphs the dense float32 forward answers exactly what
+    the float64 segment forward answers — T and λ equal, not close — for
+    solo engines, a packed graph axis (``_dense_core_multi``) and a cost
+    block axis (``_dense_core_costs``): the slope sums the argmax kernel
+    carries from level to level pick the same path among exact ties."""
+    cases = _integer_tie_cases()
+    for c in cases:
+        assert np.array_equal(c.g.econst, np.round(c.g.econst))
+    pols = {be: sweep.ExecPolicy(backend=be, cache=None)
+            for be in ("segment", "pallas")}
+    if axisset == "GS":
+        runs = [(lambda pol: sweep.Engine(
+                    [sweep.compile_plan(c.g, c.params) for c in cases],
+                    names=[c.name for c in cases], policy=pol),
+                 sweep.Query(scenarios=[c.batch for c in cases]))]
+    else:
+        runs = [(lambda pol, c=c: sweep.Engine(
+                    sweep.compile_plan(c.g, c.params), params=c.params,
+                    policy=pol),
+                 sweep.Query(scenarios=c.batch,
+                             costs=c.extras if axisset == "KS" else None))
+                for c in (cases[2:] if axisset == "KS" else cases)]
+    lams = set()
+    for make, q in runs:
+        seg, pal = (make(pols[be]).run(q) for be in ("segment", "pallas"))
+        assert (pal.backend, pal.dtype) == ("pallas", "float32")
+        assert (seg.backend, seg.dtype) == ("segment", "float64")
+        np.testing.assert_array_equal(pal.T, seg.T)
+        np.testing.assert_array_equal(pal.lam, seg.lam)
+        lams.update(np.unique(seg.lam).tolist())
+    assert len(lams) > 1                 # the races change λ along L
+
 def test_unified_engine_shards_any_axis():
     """Sharded G and K (and S) axes on a forced multi-device CPU mesh are
     bit-equal to the single-device run, for the full G×K×S query on both

@@ -97,11 +97,12 @@ def test_maxplus_argmax_matches_ref(M, N, K, bm, bn):
     t[3] = t[N - 5]
     A[7, 3] = A[7, N - 5] = 1.0
     c[3] = c[N - 5]                      # key tie too → ordinal decides
-    o, i = maxplus_matvec_argmax(A, t, c, bm=bm, bn=bn)
-    ro, ri = maxplus_matvec_argmax_ref(jnp.asarray(A), jnp.asarray(t),
-                                       jnp.asarray(c))
+    o, i, k = maxplus_matvec_argmax(A, t, c, bm=bm, bn=bn)
+    ro, ri, rk = maxplus_matvec_argmax_ref(jnp.asarray(A), jnp.asarray(t),
+                                           jnp.asarray(c))
     np.testing.assert_array_equal(np.asarray(o), np.asarray(ro))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(rk))
     assert int(np.asarray(i)[7].max()) >= 0
 
 
@@ -146,13 +147,67 @@ def test_maxplus_argmax_batched_matches_ref():
                  rng.uniform(0.0, 5.0, (G, M, N)), -1e30).astype(np.float32)
     t = rng.uniform(0.0, 50.0, (G, N, K)).astype(np.float32)
     c = rng.integers(0, 4, (G, N, K)).astype(np.float32)
-    o, i = maxplus_matvec_argmax_batched(A, t, c, bm=16, bn=16)
+    o, i, k = maxplus_matvec_argmax_batched(A, t, c, bm=16, bn=16)
     for g in range(G):
-        ro, ri = maxplus_matvec_argmax_ref(jnp.asarray(A[g]),
-                                           jnp.asarray(t[g]),
-                                           jnp.asarray(c[g]))
+        ro, ri, rk = maxplus_matvec_argmax_ref(jnp.asarray(A[g]),
+                                               jnp.asarray(t[g]),
+                                               jnp.asarray(c[g]))
         np.testing.assert_array_equal(np.asarray(o[g]), np.asarray(ro))
         np.testing.assert_array_equal(np.asarray(i[g]), np.asarray(ri))
+        np.testing.assert_array_equal(np.asarray(k[g]), np.asarray(rk))
+
+
+def _tied_argmax_case(rng, G, M, N, K):
+    """A 0/−inf adjacency with several in-edges a row and the last four
+    rows empty, integer candidate values and small integer keys: exact
+    value ties, and key ties among them, on most rows and lanes."""
+    A = np.full((G, M, N), -1e30, np.float32)
+    for g in range(G):
+        for m in range(M - 4):
+            A[g, m, rng.choice(N, size=rng.integers(2, 9),
+                               replace=False)] = 0.0
+    t = rng.integers(0, 4, (G, N, K)).astype(np.float32)
+    c = rng.integers(0, 3, (G, N, K)).astype(np.float32)
+    return A, t, c
+
+
+@pytest.mark.parametrize("G,M,N,K,bm,bn", [(1, 64, 128, 8, 32, 32),
+                                           (1, 128, 256, 128, 64, 64),
+                                           (3, 32, 64, 16, 16, 16)])
+def test_maxplus_argmax_key_is_the_winners_key(G, M, N, K, bm, bn):
+    """The key output is the tie key of the candidate the index names, bit
+    for bit, on every row and lane with a finite candidate — under forced
+    value and key ties across block boundaries — and ``NEG_INF`` on rows
+    with no in-edge; the reference agrees on all three outputs."""
+    from repro.kernels.maxplus import (maxplus_matvec_argmax,
+                                      maxplus_matvec_argmax_batched,
+                                      maxplus_matvec_argmax_ref)
+    A, t, c = _tied_argmax_case(np.random.default_rng(17 + G + M), G, M, N,
+                                K)
+    if G == 1:
+        outs = [x[None] for x in maxplus_matvec_argmax(A[0], t[0], c[0],
+                                                       bm=bm, bn=bn)]
+    else:
+        outs = maxplus_matvec_argmax_batched(A, t, c, bm=bm, bn=bn)
+    o, i, k = (np.asarray(x) for x in outs)
+    assert k.dtype == np.float32
+    lanes = np.arange(K)[None, :]
+    for g in range(G):
+        ro, ri, rk = maxplus_matvec_argmax_ref(
+            jnp.asarray(A[g]), jnp.asarray(t[g]), jnp.asarray(c[g]))
+        np.testing.assert_array_equal(o[g], np.asarray(ro))
+        np.testing.assert_array_equal(i[g], np.asarray(ri))
+        np.testing.assert_array_equal(k[g], np.asarray(rk))
+        live = o[g] >= 0.0
+        assert live[:M - 4].all() and not live[M - 4:].any()
+        assert (i[g][live] >= 0).all()
+        won = c[g][np.where(live, i[g], 0), lanes]
+        np.testing.assert_array_equal(k[g][live].view(np.uint32),
+                                      won[live].view(np.uint32))
+        assert (k[g][M - 4:] == np.float32(-1e30)).all()
+        # ties really fired: some row's max is reached by several in-edges
+        hits = (A[g][:, :, None] + t[g][None]) == o[g][:, None, :]
+        assert (hits.sum(axis=1)[live] > 1).any()
 
 
 def test_model_attention_consistent_with_kernel():

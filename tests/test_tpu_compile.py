@@ -152,9 +152,9 @@ def test_dense_f32_forward_compiles_for_v5e(one_chip, lulesh_graph,
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1 << 30
 
 
-def _level_loop_ops(hlo: str) -> list:
-    """(opcode, index-operand dims) of every gather and scatter the
-    sparse forward's level loop (``sparse_level`` scope) compiled to."""
+def _level_loop_ops(hlo: str, scope: str) -> list:
+    """(opcode, index-operand dims) of every gather and scatter a
+    forward's level loop (its ``scope`` name scope) compiled to."""
     import re
     dims = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
             for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]",
@@ -162,7 +162,7 @@ def _level_loop_ops(hlo: str) -> list:
     ops = []
     for line in hlo.splitlines():
         m = re.search(r"= \S+ (gather|scatter)\(%[\w.\-]+, %([\w.\-]+)", line)
-        if m and "/sparse_level/" in line:
+        if m and f"/{scope}/" in line:
             ops.append((m.group(1), dims.get(m.group(2))))
     return ops
 
@@ -190,7 +190,37 @@ def test_sparse_f64_level_loop_has_no_per_scenario_gather(one_chip,
         fwd = sweep_engine._get_forward(
             "sparse", True, sparse_dims=(sp.Emax_lv, sp.Vmax_lv, sp.Dmax))
         hlo = fwd.lower(*args, L, L).compile().as_text()
-    ops = _level_loop_ops(hlo)
+    ops = _level_loop_ops(hlo, "sparse_level")
     assert any(op == "gather" for op, _ in ops)
     assert not [d for op, d in ops if op == "scatter"]
     assert not [d for op, d in ops if op == "gather" and S in d]
+
+
+def test_dense_f32_level_loop_has_no_per_scenario_gather(one_chip,
+                                                         lulesh_graph,
+                                                         monkeypatch):
+    """The dense Pallas λ forward at ``lulesh_64r``'s envelope and the
+    ``grid_f32`` traffic's 512 scenarios takes each level's new slope sums
+    from the argmax kernel's key output: its level loop (``dense_level``
+    scope) gathers only through indices every scenario shares, and still
+    holds the kernel."""
+    from repro import sweep
+    from repro.kernels.maxplus import ops as kops
+    from repro.sweep import engine as sweep_engine
+    g, p = lulesh_graph(4, 6, 0.1, 1)
+    eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(
+        backend="pallas", cache=None))
+    args = [jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+            for a in eng._arrays("pallas")]
+    S = 512
+    assert S not in args[0].shape
+    L = jax.ShapeDtypeStruct((S, 1), jnp.float32, sharding=one_chip)
+    monkeypatch.setattr(kops, "resolve_interpret",
+                        lambda interpret=None: False)
+    hlo = sweep_engine._get_forward("pallas", True).lower(
+        *args, L, L).compile().as_text()
+    ops = _level_loop_ops(hlo, "dense_level")
+    assert any(op == "gather" for op, _ in ops)
+    assert not [d for op, d in ops if op == "gather" and S in d]
+    assert any("tpu_custom_call" in line and "/dense_level/" in line
+               for line in hlo.splitlines())
