@@ -5,12 +5,12 @@ Two acceptance bars, measured here:
 * single graph: a 1,000-scenario LogGPS grid must evaluate ≥10× faster per
   scenario than calling ``dag.LevelPlan.forward`` in a Python loop, with
   identical results (1e-6).
-* variant study (multi-graph packing): a 4-variant × 250-scenario collective
-  study — four graphs in four *different* shape buckets — must run as one
-  packed :class:`~repro.sweep.MultiPlan` call and beat the per-variant
-  jit loop by ≥3× cold wall-clock.  The per-variant loop pays one XLA
-  compile per distinct shape; the packed study pays one compile for the
-  common envelope.  Results must agree bit-for-bit.
+* variant study (``structure_patch``): a 4-variant × 250-scenario
+  collective study — four graphs in four *different* shape buckets — runs
+  as one ``Query(structure=)`` dispatch on a
+  :class:`~repro.sweep.StructureBatch` envelope and compiles one XLA
+  program, where the per-variant loop pays one compile per distinct
+  shape.  Results must agree bit-for-bit.
 
 Also reported: the values-only fast path, the Pallas (max,+) backend on a
 small grid (values AND λ — the argmax-emitting kernel, no segment
@@ -54,7 +54,7 @@ def single_graph(out, n_scenarios=N_SCENARIOS):
 
     eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
     t_batch, res = timeit(lambda: eng.run(grid), repeats=2, warmup=1)
-    t_vals, _ = timeit(lambda: eng.run(grid, compute_lam=False),
+    t_vals, _ = timeit(lambda: eng.run(grid, outputs=("T",)),
                        repeats=2, warmup=1)
 
     plan = dag.LevelPlan(g)
@@ -83,66 +83,6 @@ def single_graph(out, n_scenarios=N_SCENARIOS):
     out(csv_line("sweep.cache_hit", t_hit * 1e6, f"scenarios={n_scenarios}"))
 
 
-def variant_study(out, n_scenarios=STUDY_SCENARIOS):
-    """4-variant × n-scenario collective study: packed MultiPlan vs the
-    per-variant jit loop, cold wall-clock (compiles included on both sides).
-
-    The four allreduce expansions land in four different shape buckets
-    (ring/bidir/recursive-doubling/tree have very different round counts),
-    so the per-variant loop compiles four XLA programs where the packed
-    study compiles one.  Measured both ways: values-only (what a ranking
-    study — ``AnalysisService.rank`` — actually runs) and the full T/λ/ρ
-    study.  Run this module standalone for honest cold numbers; inside
-    ``benchmarks.run`` earlier modules may have warmed unrelated programs
-    but never these shapes.
-    """
-    p = cluster_params(L_us=3.0, o_us=5.0)
-    variants = sweep.collective_variants(
-        lambda a: synth.allreduce_chain(8, 1, params=p, algo=a),
-        list(STUDY_ALGOS), p)
-    deltas = np.linspace(0.0, 100.0, n_scenarios)
-    batch_of = lambda v: sweep.latency_grid(p, deltas)  # noqa: E731
-
-    import warnings
-
-    for tag, lam in (("values", False), ("lam", True)):
-        # cache=None: timings and call-count asserts must measure compiled
-        # dispatches, not content-hash hits from an earlier run.  This
-        # section deliberately times the deprecated sweep_variants shim
-        # (now a thin wrapper over Query(structure=)), so silence its
-        # DeprecationWarning — structure_patch times the new API directly.
-        stats_pv, stats_b = {}, {}
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            t0 = time.perf_counter()
-            pv = sweep.sweep_variants(variants, batch_of, batched=False,
-                                      compute_lam=lam, stats=stats_pv,
-                                      cache=None)
-            t_pv = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            bat = sweep.sweep_variants(variants, batch_of, batched=True,
-                                       compute_lam=lam, stats=stats_b,
-                                       cache=None)
-            t_b = time.perf_counter() - t0
-
-        # one compiled call per shape bucket, not one per variant
-        assert stats_pv["calls"] == len(variants)
-        assert stats_b["calls"] == stats_b["groups"] < len(variants), stats_b
-        for name in pv:                       # packed ≡ solo, bit for bit
-            assert np.array_equal(pv[name].T, bat[name].T), name
-            if lam:
-                assert np.array_equal(pv[name].lam, bat[name].lam), name
-
-        speedup = t_pv / t_b
-        out(csv_line(
-            f"sweep.variant_study.{tag}.batched", t_b * 1e6,
-            f"variants={len(variants)};scenarios={n_scenarios};"
-            f"calls={stats_b['calls']};speedup_vs_pervariant={speedup:.1f}x"))
-        out(csv_line(
-            f"sweep.variant_study.{tag}.pervariant", t_pv * 1e6,
-            f"calls={stats_pv['calls']};compiles_per_shape=1"))
-
-
 def pallas_backend(out, n_scenarios=64):
     # pallas (max,+) inner-scatter backend, small graph + grid (on the CPU
     # backend the kernel runs in interpret mode, so keep this smoke-scale)
@@ -152,7 +92,7 @@ def pallas_backend(out, n_scenarios=64):
     grid_small = sweep.latency_grid(p, np.linspace(0.0, 50.0, n_scenarios))
     seg = eng_p.run(grid_small)
     t_pal, pal = timeit(lambda: eng_p.run(grid_small, backend="pallas",
-                                          compute_lam=False),
+                                          outputs=("T",)),
                         repeats=2, warmup=1)
     rel = float(np.max(np.abs(pal.T - seg.T) / seg.T))
     # float32 accumulators (TPU VPU layout) → relative tolerance
@@ -161,8 +101,7 @@ def pallas_backend(out, n_scenarios=64):
                  f"rel_vs_segment={rel:.1e}"))
 
     # λ/ρ straight from the argmax-emitting kernel — no segment redirect
-    t_lam, pal_lam = timeit(lambda: eng_p.run(grid_small, backend="pallas",
-                                              compute_lam=True),
+    t_lam, pal_lam = timeit(lambda: eng_p.run(grid_small, backend="pallas"),
                             repeats=2, warmup=1)
     assert pal_lam.backend == "pallas", pal_lam.backend
     rel_l = float(np.max(np.abs(pal_lam.lam - seg.lam)))
@@ -317,7 +256,7 @@ def placement_patch(out, smoke: bool = False):
               for _ in range(topk)]
     EX = np.stack(extras)
     t_patch_step, res = timeit(
-        lambda: eng.run(scen, costs=EX, compute_lam=False),
+        lambda: eng.run(scen, costs=EX, outputs=("T",)),
         repeats=5, warmup=2)
     t_reb_step, ref = timeit(
         lambda: placement._candidate_objectives(g, scen, extras, "segment"),
@@ -856,7 +795,6 @@ def sharded(out, n_scenarios=16, ndev=2):
 def run(out, smoke: bool = False):
     if smoke:
         single_graph(out, n_scenarios=64)
-        variant_study(out, n_scenarios=50)
         pallas_backend(out, n_scenarios=16)
         lam_compile(out, n_scenarios=32)
         sharded(out, n_scenarios=16)
@@ -868,7 +806,6 @@ def run(out, smoke: bool = False):
         resilience(out, smoke=True)
         return
     single_graph(out)
-    variant_study(out)
     pallas_backend(out)
     lam_compile(out)
     sharded(out, n_scenarios=64)

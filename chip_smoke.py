@@ -107,8 +107,14 @@ def _peak_bytes():
 def _timed(fn, warm_reps: int = 3) -> tuple:
     """Run ``fn`` cold, then ``warm_reps`` times warm.  ``fn`` returns host
     (numpy) arrays, so every timing ends after the device results were
-    read back.  Returns (first result, stats dict)."""
+    read back.  The cold call starts from empty in-process compile
+    caches, so ``first_s`` and ``xla_programs`` are that call's own
+    whatever the process ran before; the persistent compilation cache
+    stays, and ``compile_s`` includes its retrievals.  Returns (first
+    result, stats dict)."""
+    import jax
     from repro.obs.compile import CompileWatcher
+    jax.clear_caches()
     clock = _clock()
     c0 = clock.seconds
     with CompileWatcher().watch("chip_smoke") as rec:

@@ -13,12 +13,12 @@ import numpy as np
 
 from repro import sweep
 from repro.core import synth
-from repro.core.loggps import tpu_pod_params
+from repro.core.loggps import pod_model
 
 
 def main():
     # an HPCG-like CG solve on 2 TPU pods: class 0 = ICI, class 1 = DCN
-    p = tpu_pod_params(pod_size=8, L_ici_us=1.0, L_dcn_us=10.0)
+    p = pod_model(pod_size=8, L_ici_us=1.0, L_dcn_us=10.0).params()
     g = synth.cg_like(4, 4, 6, params=p)
     print(f"workload: {g.summary()}\n")
 

@@ -11,8 +11,8 @@ Public API:
     sensitivity.analyze / latency_curve / latency_tolerance
     topology / placement / synth / tracer / hlo
 
-Batched scenario sweeps live in the sibling package ``repro.sweep``: a
-SweepEngine compiles an ExecutionGraph once into padded per-level tensors
+Batched scenario sweeps live in the sibling package ``repro.sweep``: an
+Engine compiles an ExecutionGraph once into padded per-level tensors
 and evaluates thousands of LogGPS parameter points (latency deltas ×
 bandwidth scales, plus stamped collective/topology graph variants) in one
 jit+vmap max-plus pass, with results identical to ``dag.evaluate``.  The
@@ -24,5 +24,5 @@ from . import (collectives, dag, graph, hlo, ipm, loggps, lp, placement,  # noqa
                sensitivity, simulator, synth, topology)
 from .graph import ExecutionGraph, GraphBuilder  # noqa: F401
 from .loggps import (LogGPS, NetClass, NetworkModel, cluster_params,  # noqa: F401
-                     pod_model, resolve_class, tpu_pod_params)
+                     pod_model, resolve_class)
 from .sensitivity import analyze, latency_curve, latency_tolerance  # noqa: F401

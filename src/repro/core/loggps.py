@@ -24,7 +24,6 @@ available but default it to 0 for graph analyses (the DES honors it).
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -213,8 +212,7 @@ def pod_model(pod_size: int, ranks_per_host: Optional[int] = None,
 
     Ranks are laid out pod-major (and host-major within a pod).  With
     ``ranks_per_host=None`` the model has exactly the two classic classes
-    ("ici", "dcn") and is value-identical to the historical
-    ``tpu_pod_params``.  ``alpha``/``beta`` are optional dicts keyed by
+    ("ici", "dcn").  ``alpha``/``beta`` are optional dicts keyed by
     class name setting per-class congestion parameters.  S defaults to
     effectively-infinite: XLA collectives are one-sided DMA (no rendezvous
     handshake at the LogGPS level).
@@ -254,25 +252,6 @@ def pod_model(pod_size: int, ranks_per_host: Optional[int] = None,
 
     return NetworkModel(classes=classes, rank_of_class=link_class,
                         o=o_us, S=S_bytes)
-
-
-def tpu_pod_params(pod_size: int, L_ici_us: float = 1.0, L_dcn_us: float = 10.0,
-                   ici_gbps: float = 50.0, dcn_gbps: float = 25.0,
-                   o_us: float = 0.5, S_bytes: float = 1e9) -> LogGPS:
-    """Deprecated: two-class TPU parameters (class 0 = ICI, class 1 = DCN).
-
-    Compatibility shim over the class registry — build network models via
-    :func:`pod_model` (``pod_model(pod_size, ...).params()``), which also
-    exposes the intra-node class and per-class congestion parameters.
-    Results are bit-identical to the historical constructor.
-    """
-    warnings.warn(
-        "tpu_pod_params() is deprecated; use "
-        "pod_model(pod_size, ...).params() (repro.core.loggps) instead",
-        DeprecationWarning, stacklevel=2)
-    return pod_model(pod_size, L_ici_us=L_ici_us, L_dcn_us=L_dcn_us,
-                     ici_gbps=ici_gbps, dcn_gbps=dcn_gbps,
-                     o_us=o_us, S_bytes=S_bytes).params()
 
 
 def edge_costs(graph, params: LogGPS) -> tuple[np.ndarray, np.ndarray]:

@@ -23,7 +23,7 @@ step instead of scalar re-solves.  Candidate evaluation is
 ONCE and each candidate mapping's Φ link costs patch into the warm plan's
 cost block as a runtime input
 (:meth:`~repro.sweep.compile.CompiledPlan.patch_costs` +
-``SweepEngine.run(costs=...)``) — bit-identical objectives, and therefore
+``Engine.run(costs=...)``) — bit-identical objectives, and therefore
 the same final mapping, as ``cost_eval="rebuild"`` (K fresh CompiledPlans
 packed into a MultiPlan per step, the previous formulation, kept as the
 reference).  With the default single-point grid and ``topk=1`` it
@@ -208,7 +208,7 @@ def _candidate_objectives(g, scen_batch, extras, backend):
 
     plans = [compile_plan(g, extra_edge_cost=ex) for ex in extras]
     eng = Engine(plans, policy=ExecPolicy(backend=backend, cache=None))
-    res = eng.run(scen_batch, compute_lam=False)
+    res = eng.run(scen_batch, outputs=("T",))
     return res.T.mean(axis=1)                  # [K] mean over the grid
 
 
@@ -351,7 +351,7 @@ def place(g: ExecutionGraph, phi: ArchTopology, params: Optional[LogGPS] = None,
 
     ``cost_eval="patch"`` (default) is the zero-recompile path: the graph
     compiles ONCE and every candidate mapping's Φ costs patch into the
-    warm plan as a runtime input (``SweepEngine.run(costs=...)``);
+    warm plan as a runtime input (``Engine.run(costs=...)``);
     ``cost_eval="rebuild"`` recompiles K plans per step (the equivalence
     reference — same objectives bit for bit, so the same final mapping).
     ``backend`` picks the compiled evaluator, ``cache`` (a ``SweepCache``)

@@ -137,34 +137,25 @@ def _params_memo_key(g: ExecutionGraph, params: LogGPS) -> tuple:
 
 
 def _sweep_engine(g: ExecutionGraph, params: LogGPS, policy=None):
-    """Build (or reuse) a batched engine.
+    """Build (or reuse) the batched :class:`repro.sweep.api.Engine`.
 
     Compiled engines are memoized on the graph object per parameter set
     (content-keyed, see :func:`_params_memo_key`) and per execution
     policy, so repeated sensitivity calls on one graph pay compile_plan
-    once.  With ``policy=None`` the engine is the legacy ``SweepEngine``
-    shim (its DeprecationWarning suppressed — this module's own surface is
-    the ``engine=``/``policy=`` kwargs, not the shim); an explicit
-    :class:`repro.sweep.api.ExecPolicy` builds the unified
-    :class:`repro.sweep.api.Engine` directly.
+    once.  ``policy=None`` runs under the default ``ExecPolicy()`` and
+    shares its engine with an explicit one.
     """
-    from repro.sweep import SweepEngine
+    from repro.sweep.api import Engine, ExecPolicy
+    if policy is None:
+        policy = ExecPolicy()
     memo = getattr(g, "_sweep_engines", None)
     if memo is None:
         memo = {}
         object.__setattr__(g, "_sweep_engines", memo)
-    key = _params_memo_key(g, params) \
-        + (None if policy is None else policy.key(),)
+    key = _params_memo_key(g, params) + (policy.key(),)
     eng = memo.get(key)
     if eng is None:
-        if policy is None:
-            import warnings
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore", DeprecationWarning)
-                eng = SweepEngine(g, params)
-        else:
-            from repro.sweep.api import Engine
-            eng = Engine(g, params=params, policy=policy)
+        eng = Engine(g, params=params, policy=policy)
         memo[key] = eng
     return eng
 
@@ -297,12 +288,8 @@ def resilience_curve(g: ExecutionGraph, params: LogGPS, faults: Sequence,
     res = None
     if engine != "scalar":
         try:
-            from repro.sweep.api import ExecPolicy, Query
-            # an explicit unified-Engine policy (the legacy shim has no
-            # structure axis)
-            eng = _sweep_engine(g, params,
-                                policy if policy is not None
-                                else ExecPolicy())
+            from repro.sweep.api import Query
+            eng = _sweep_engine(g, params, policy)
             ax = fault_axes(g, params, faults, plan=eng.plan)
             res = eng.run(Query(scenarios=ax.scenarios, costs=ax.extras,
                                 structure=ax.structure))

@@ -4,9 +4,9 @@ The LLAMP workflow an operator actually runs is interactive: "here are my
 candidate collective algorithms / topologies / placements — how does each
 behave as DCN latency degrades, and which one should I deploy?"  Answering
 that cold means re-compiling a sweep program per question.  This service
-keeps the expensive artifacts warm — one :class:`~repro.sweep.SweepEngine`
-per registered variant, one packed
-:class:`~repro.sweep.MultiSweepEngine` per shape bucket, and a shared
+keeps the expensive artifacts warm — one :class:`~repro.sweep.Engine`
+per registered variant, one packed multi-graph
+:class:`~repro.sweep.Engine` per shape bucket, and a shared
 :class:`~repro.sweep.SweepCache` of results — so every query after the
 first is a jit dispatch (or a cache hash) instead of a compile.
 
@@ -329,7 +329,7 @@ class AnalysisService:
                            for n in names]
                 meng.run(batches, use_cache=False)
                 # rank queries run values-only — pre-build that program too
-                meng.run(batches, compute_lam=False, use_cache=False)
+                meng.run(batches, outputs=("T",), use_cache=False)
         return {"variants": len(self._variants), "buckets": len(buckets),
                 "bucket_sizes": [len(ns) for ns, _ in buckets],
                 "warm_s": time.perf_counter() - t0}

@@ -31,9 +31,6 @@ sharding over any populated axis, exact-vs-finite-difference λ, cache):
 Public surface (re-exported here):
     Engine / Query / ExecPolicy / Result — the unified axis-oriented API
                                         (repro.sweep.api)
-    SweepEngine / MultiSweepEngine    — DEPRECATED shims over Engine
-                                        (bit-identical; DeprecationWarning)
-    SweepResult / MultiSweepResult / CostSweepResult — legacy result shapes
     CompiledPlan / compile_plan       — graph → bucketed rectangular tensors
                                         (immutable structure + patchable
                                         cost block, see COST_FIELDS)
@@ -55,8 +52,8 @@ Public surface (re-exported here):
     ScenarioBatch + grid builders     — latency_grid / bandwidth_grid /
                                         cartesian_grid / base_batch
     GraphVariant stamping             — collective_variants / topology_variants
-                                        / sweep_variants (axes that change the
-                                        graph itself)
+                                        (axes that change the graph itself;
+                                        run them through StructureBatch)
     Fault & straggler scenarios       — StragglerFault / LinkFault /
                                         DeviceFault + fault_axes /
                                         recovery_cost_us: a fault
@@ -92,12 +89,9 @@ from .compile import (COST_FIELDS, STRUCT_FIELDS, CompiledPlan,  # noqa: F401
                       CostBatch, MultiPlan, SparsePlan, StructureBatch,
                       compile_plan, compile_sparse, estimate_dense_bytes,
                       group_plans, pack_plans, repad_plan)
-from .engine import (CostSweepResult, MultiSweepEngine,  # noqa: F401
-                     MultiSweepResult, SweepEngine, SweepResult,
-                     breakpoints_batched, tolerance_batched)
+from .engine import breakpoints_batched, tolerance_batched  # noqa: F401
 from .scenarios import (DeviceFault, FaultAxes, GraphVariant,  # noqa: F401
                         LinkFault, ScenarioBatch, StragglerFault,
                         bandwidth_grid, base_batch, cartesian_grid,
                         collective_variants, fault_axes, latency_grid,
-                        recovery_cost_us, sample_grid, sweep_variants,
-                        topology_variants)
+                        recovery_cost_us, sample_grid, topology_variants)

@@ -35,11 +35,8 @@ policy; this module folds them back into three objects:
     >>> res = eng.run(Query(scenarios=grid, costs=[extras_a, extras_b]))
     >>> res.T.shape                     # [G, K, S]
 
-The legacy ``SweepEngine`` / ``MultiSweepEngine`` classes are thin
-deprecation-warned shims over this engine (bit-identical results, verified
-by ``tests/test_conformance.py``); ``core.sensitivity``,
-``core.placement.place`` and ``launch.analysis`` all build a
-``Query`` + ``ExecPolicy`` instead of threading loose kwargs.
+``core.sensitivity``, ``core.placement.place`` and ``launch.analysis``
+all build a ``Query`` + ``ExecPolicy`` instead of threading loose kwargs.
 """
 
 from __future__ import annotations
@@ -560,9 +557,7 @@ class Engine:
 
     The engine stages plan tensors per backend once, resolves each run's
     populated axes, and dispatches through the shared jit cells of
-    ``repro.sweep.engine._get_forward`` — the *same* compiled programs the
-    legacy engines used for their combinations, which is what makes the
-    legacy shims bit-identical by construction.
+    ``repro.sweep.engine._get_forward``.
     """
 
     MAX_DENSE_BYTES = 256 << 20
@@ -846,8 +841,8 @@ class Engine:
 
     # -- the run -------------------------------------------------------------
     def run(self, query=None, *, scenarios=None, costs=None, structure=None,
-            outputs=None, compute_lam=None, backend=None, shard=None,
-            shard_axis=None, use_cache: bool = True,
+            outputs=None, backend=None, shard=None, shard_axis=None,
+            use_cache: bool = True,
             policy: Optional[ExecPolicy] = None) -> Result:
         """Evaluate one query; returns a numpy-backed :class:`Result`.
 
@@ -855,8 +850,8 @@ class Engine:
         per-graph sequence), or None with keyword axes.  ``policy``
         replaces the engine's policy wholesale for this run; the
         individual ``backend``/``shard``/``shard_axis`` keywords override
-        single fields.  ``compute_lam`` is the legacy spelling of
-        ``outputs`` (True → T/λ/ρ, False → T only).
+        single fields.  An ``outputs`` keyword wins over the Query's: a
+        T-only run never compiles or runs the λ program.
         """
         if isinstance(query, Query):
             if query.graphs is not None:
@@ -868,9 +863,8 @@ class Engine:
                 return sub.run(dataclasses.replace(query, graphs=None,
                                                    params=None),
                                structure=structure, outputs=outputs,
-                               compute_lam=compute_lam, backend=backend,
-                               shard=shard, shard_axis=shard_axis,
-                               use_cache=use_cache)
+                               backend=backend, shard=shard,
+                               shard_axis=shard_axis, use_cache=use_cache)
             scenarios = query.scenarios if scenarios is None else scenarios
             costs = query.costs if costs is None else costs
             structure = query.structure if structure is None else structure
@@ -891,12 +885,7 @@ class Engine:
             pol = dataclasses.replace(pol, **over)
         pol.validate()
 
-        if compute_lam is not None:
-            # the legacy flag is an explicit ask — it wins even over a
-            # Query's (defaulted) outputs tuple, so run(q, compute_lam=
-            # False) never silently pays for the λ program
-            outputs = _OUTPUTS if compute_lam else ("T",)
-        elif outputs is None:
+        if outputs is None:
             outputs = _OUTPUTS
         outputs = tuple(outputs)
         bad = set(outputs) - set(_OUTPUTS)

@@ -163,7 +163,7 @@ class CacheStats:
 
 
 class SweepCache:
-    """LRU map: result_key → SweepResult (or Multi/CostSweepResult).
+    """LRU map: result key → :class:`~repro.sweep.api.Result`.
 
     Thread-safe: the analysis service's threaded socket server shares one
     instance across connections, so every read-modify-write on the LRU

@@ -54,7 +54,7 @@ shares, latency-class rows) is plain data the program consumes.  Because
 order (``epos_*``), new per-edge costs patch into a warm plan as a runtime
 input instead of a rebuild: :meth:`CompiledPlan.patch_costs` stacks K
 candidate cost blocks into a :class:`CostBatch` that
-``SweepEngine.run(costs=...)`` vmaps alongside scenarios — the zero-
+``Engine.run(costs=...)`` vmaps alongside scenarios — the zero-
 recompile path behind the Algorithm-3 placement search (every swap
 candidate of every greedy step reuses ONE compiled program).  Patched
 costs are bit-identical to rebuilding the plan with
@@ -132,7 +132,7 @@ class CostBatch:
     Leading axis = candidate index (e.g. the K swap candidates of one
     greedy placement step).  Tensors that a patch did not touch are
     broadcast views of the parent plan's — only the patched constants are
-    materialized K times.  ``SweepEngine.run(costs=...)`` vmaps the blocks
+    materialized K times.  ``Engine.run(costs=...)`` vmaps the blocks
     alongside the scenario axis through the plan's already-compiled
     forward; the structure tensors ride along unbatched, so no new XLA
     program is ever built for a new cost block.

@@ -3,9 +3,7 @@
 This module owns the jitted cores and the populated-axis forward cache
 (:func:`_get_forward`): graph [G], candidate-cost [K] and scenario [S]
 batch axes compose freely via vmap (and any one of them can shard across
-devices).  The user-facing evaluator is :class:`repro.sweep.api.Engine`;
-the :class:`SweepEngine` / :class:`MultiSweepEngine` classes below are
-deprecation-warned shims over it, kept bit-identical for legacy callers.
+devices).  The user-facing evaluator is :class:`repro.sweep.api.Engine`.
 
 One call evaluates a whole :class:`~repro.sweep.scenarios.ScenarioBatch`
 against a :class:`~repro.sweep.compile.CompiledPlan`:
@@ -56,11 +54,10 @@ backtrace scan — bit-identical to the original fused single-loop backtrace
 (kept under ``fused=True`` as the reference) at roughly the values-only
 program's compile cost.
 
-Device sharding: ``run(..., shard=...)`` splits the scenario axis
-(:class:`SweepEngine`) or the MultiPlan's leading graph axis
-(:class:`MultiSweepEngine`) across local devices with ``shard_map``;
-per-element arithmetic is unchanged, so sharded results are bit-identical
-to single-device runs.
+Device sharding: ``ExecPolicy(shard=...)`` splits one populated axis — the
+scenarios, the MultiPlan's leading graph axis or the candidate-cost axis —
+across local devices with ``shard_map``; per-element arithmetic is
+unchanged, so sharded results are bit-identical to single-device runs.
 
 Candidate costs: ``run(..., costs=CostBatch)`` adds a third batch axis —
 K patched cost blocks (same plan structure, new per-edge constants) vmap
@@ -83,81 +80,20 @@ round becomes ONE engine call over all active intervals.
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Optional, Sequence
+from typing import TYPE_CHECKING, Optional, Sequence
 
 import numpy as np
 
 from repro.core.loggps import LogGPS
 
-from .cache import DEFAULT_CACHE, SweepCache
-from .compile import (CompiledPlan, CostBatch, MultiPlan,  # noqa: F401
-                      _bucket, compile_plan)
-from .scenarios import ScenarioBatch, latency_grid
+from .compile import _bucket
+from .scenarios import latency_grid
+
+if TYPE_CHECKING:
+    from .api import Engine
 
 BIG = 1e30          # matches kernels.maxplus NEG_INF magnitude
 ATOL = 1e-12        # the scalar engine's tie tolerances (dag.LevelPlan)
-
-
-@dataclasses.dataclass
-class SweepResult:
-    T: np.ndarray                    # [S] µs
-    lam: Optional[np.ndarray]        # [S, nclass] or None (values-only run)
-    rho: Optional[np.ndarray]        # [S, nclass] or None
-    scenarios: ScenarioBatch
-    backend: str
-    from_cache: bool = False
-
-    @property
-    def S(self) -> int:
-        return int(self.T.shape[0])
-
-    def argbest(self) -> int:
-        """Scenario index with the smallest makespan."""
-        return int(np.argmin(self.T))
-
-
-@dataclasses.dataclass
-class CostSweepResult:
-    """Per-candidate sweep tensors: row k is cost block k of the
-    :class:`~repro.sweep.compile.CostBatch` the run patched in."""
-
-    T: np.ndarray                    # [K, S] µs
-    lam: Optional[np.ndarray]        # [K, S, nclass] or None
-    rho: Optional[np.ndarray]        # [K, S, nclass] or None
-    scenarios: ScenarioBatch
-    backend: str
-    from_cache: bool = False
-
-    @property
-    def K(self) -> int:
-        return int(self.T.shape[0])
-
-    @property
-    def S(self) -> int:
-        return int(self.T.shape[1])
-
-    def __getitem__(self, k: int) -> SweepResult:
-        """Candidate k's slice as a plain :class:`SweepResult`."""
-        k = int(k)
-        return SweepResult(
-            T=self.T[k].copy(),
-            lam=None if self.lam is None else self.lam[k].copy(),
-            rho=None if self.rho is None else self.rho[k].copy(),
-            scenarios=self.scenarios, backend=self.backend,
-            from_cache=self.from_cache)
-
-    def argbest(self, reduce: str = "mean") -> int:
-        """Candidate index minimizing the makespan objective over the grid."""
-        if reduce == "mean":
-            obj = self.T.mean(axis=1)
-        elif reduce == "max":
-            obj = self.T.max(axis=1)
-        elif reduce == "final":
-            obj = self.T[:, -1]
-        else:
-            raise ValueError(f"unknown reduce {reduce!r}")
-        return int(np.argmin(obj))
 
 
 # -- jitted forwards (module level: the jit cache is shared across engines,
@@ -185,7 +121,7 @@ def _make_segment_one(want_lam: bool, fused: bool = False):
     in-edges, so one level is a gather → max over the in-edge axis →
     ``dynamic_update_slice`` of the level's slot block — scatter-free, which
     is what makes the sweep fast on CPU/TPU alike.  ``vmap``'d over the
-    scenario axis (and, for :class:`MultiSweepEngine`, the graph axis:
+    scenario axis (and, for a packed MultiPlan, the graph axis:
     padding only adds masked −∞ candidates and max is exact, so a packed
     graph's outputs are bit-identical to its solo run).
 
@@ -1106,7 +1042,8 @@ def _resolve_shard(shard, size: int) -> Optional[int]:
 def _stage_arrays(plan, kind: str, max_dense_bytes: int) -> tuple:
     """Device-stage a plan's tensors for one backend.  CompiledPlan and
     MultiPlan share field names (the latter just carries a leading graph
-    axis), so both engines stage through this one helper."""
+    axis), so single- and multi-graph engines stage through this one
+    helper."""
     jnp = _jax().numpy
     if kind == "segment":
         return tuple(jnp.asarray(a) for a in (
@@ -1210,15 +1147,15 @@ def _get_forward(kind: str, want_lam: bool = False, multi: bool = False,
 
     The cell is keyed on (backend, λ, G axis, K axes, mesh, shard axis):
     vmap composition over the populated batch axes is derived here
-    (``_segment_core_axes`` / ``_dense_core_axes``) rather than from which
-    engine class a caller instantiated — graph [G], candidate-cost [K] and
-    scenario [S] axes compose freely, including all at once.
+    (``_segment_core_axes`` / ``_dense_core_axes``) — graph [G],
+    candidate-cost [K] and scenario [S] axes compose freely, including all
+    at once.
 
     With ``mesh`` the composed core is wrapped in ``shard_map`` before
     jit; ``shard_axis`` picks which populated axis splits across devices
-    (default: the MultiPlan graph axis when present, else scenarios — the
-    legacy engines' behavior).  Per-element arithmetic is unchanged either
-    way, so sharded results are bit-identical to single-device runs.
+    (default: the MultiPlan graph axis when present, else scenarios).
+    Per-element arithmetic is unchanged either way, so sharded results are
+    bit-identical to single-device runs.
 
     ``costs`` (a per-cost-field vmap-axis tuple, see ``_SEG_COST_FIELDS``
     / ``_PAL_COST_FIELDS``) selects the candidate-cost-axis cells:
@@ -1285,274 +1222,18 @@ def _get_forward(kind: str, want_lam: bool = False, multi: bool = False,
     return fn
 
 
-def _warn_deprecated_shim(old: str) -> None:
-    import warnings
-    warnings.warn(
-        f"{old} is deprecated; build a repro.sweep.Engine with an "
-        "ExecPolicy and run a Query instead (one engine, G/K/S batch "
-        "axes — see repro.sweep.api).  This shim delegates to the unified "
-        "engine and stays bit-identical.",
-        DeprecationWarning, stacklevel=3)
-
-
-class SweepEngine:
-    """DEPRECATED shim over :class:`repro.sweep.api.Engine` (single graph).
-
-    Compile once, evaluate thousands of LogGPS scenarios per call:
-
-    >>> eng = SweepEngine(graph, params)
-    >>> res = eng.run(latency_grid(params, np.linspace(0, 100, 1000)))
-    >>> res.T, res.lam, res.rho     # [1000], [1000, nclass], [1000, nclass]
-
-    The unified engine dispatches the *same* jit cells this class used to
-    own, so results (λ tie-breaks included) are bit-identical; new code
-    should construct ``Engine``/``Query``/``ExecPolicy`` directly.
-    """
-
-    MAX_DENSE_BYTES = 256 << 20
-
-    def __init__(self, graph=None, params: Optional[LogGPS] = None,
-                 backend: str = "segment", shard=None,
-                 compiled: Optional[CompiledPlan] = None,
-                 cache: Optional[SweepCache] = DEFAULT_CACHE):
-        _warn_deprecated_shim("SweepEngine")
-        from .api import Engine, ExecPolicy
-        if compiled is None:
-            if graph is None:
-                raise ValueError("need a graph or a CompiledPlan")
-            compiled = compile_plan(graph, params)
-        self._eng = Engine(compiled, params=params,
-                           policy=ExecPolicy(backend=backend, shard=shard,
-                                             cache=cache))
-        # honor a subclass/class-level override of the dense-size guard
-        self._eng.MAX_DENSE_BYTES = type(self).MAX_DENSE_BYTES
-
-    # -- legacy attribute surface (read-through to the unified engine) -------
-    @property
-    def compiled(self) -> CompiledPlan:
-        return self._eng.plan
-
-    @property
-    def params(self):
-        return self._eng.params
-
-    @property
-    def backend(self) -> str:
-        return self._eng.policy.backend
-
-    @property
-    def shard(self):
-        return self._eng.policy.shard
-
-    @property
-    def cache(self):
-        return self._eng.policy.cache
-
-    @property
-    def calls(self) -> int:
-        return self._eng.calls
-
-    def _arrays(self, kind: str):
-        return self._eng._arrays(kind)
-
-    def run(self, scenarios: ScenarioBatch, compute_lam: bool = True,
-            backend: Optional[str] = None, shard=None,
-            use_cache: bool = True, costs: Optional[CostBatch] = None):
-        """Evaluate every scenario; returns numpy-backed :class:`SweepResult`
-        (or :class:`CostSweepResult` when ``costs`` populates the candidate
-        axis).  ``shard`` now composes with ``costs`` — the unified engine
-        shards whichever axis the policy picks (scenarios by default)."""
-        res = self._eng.run(scenarios=scenarios, compute_lam=compute_lam,
-                            backend=backend, shard=shard,
-                            use_cache=use_cache, costs=costs)
-        if "K" in res.axes:
-            return CostSweepResult(T=res.T, lam=res.lam, rho=res.rho,
-                                   scenarios=res.scenarios,
-                                   backend=res.backend,
-                                   from_cache=res.from_cache)
-        return SweepResult(T=res.T, lam=res.lam, rho=res.rho,
-                           scenarios=res.scenarios, backend=res.backend,
-                           from_cache=res.from_cache)
-
-    def latency_curve(self, deltas: Sequence[float], cls: int = 0,
-                      params: Optional[LogGPS] = None,
-                      compute_lam: bool = True) -> SweepResult:
-        p = params or self.params
-        if p is None:
-            raise ValueError("engine has no params; pass params=")
-        return self.run(latency_grid(p, deltas, cls=cls),
-                        compute_lam=compute_lam)
-
-
-# -- multi-graph engine: (graph × scenario) in one compiled program -----------
-
-@dataclasses.dataclass
-class MultiSweepResult:
-    """Per-graph sweep tensors: row g is graph g's :class:`SweepResult`."""
-
-    T: np.ndarray                    # [G, S] µs
-    lam: Optional[np.ndarray]        # [G, S, nclass] or None
-    rho: Optional[np.ndarray]        # [G, S, nclass] or None
-    scenarios: list                  # per-graph ScenarioBatch
-    names: tuple
-    backend: str
-    from_cache: bool = False
-
-    @property
-    def G(self) -> int:
-        return int(self.T.shape[0])
-
-    @property
-    def S(self) -> int:
-        return int(self.T.shape[1])
-
-    def __getitem__(self, key) -> SweepResult:
-        """Graph g's slice as a plain :class:`SweepResult` (by index or name)."""
-        g = self.names.index(key) if isinstance(key, str) else int(key)
-        return SweepResult(
-            T=self.T[g].copy(),
-            lam=None if self.lam is None else self.lam[g].copy(),
-            rho=None if self.rho is None else self.rho[g].copy(),
-            scenarios=self.scenarios[g], backend=self.backend,
-            from_cache=self.from_cache)
-
-    def split(self) -> dict:
-        """{name: SweepResult} — the ``sweep_variants`` return shape."""
-        return {name: self[i] for i, name in enumerate(self.names)}
-
-    def rank(self, reduce: str = "mean") -> list:
-        """Variants ordered best-first by makespan over the grid.
-
-        ``reduce``: 'mean' | 'max' | 'final' (last scenario row — e.g. the
-        worst latency point of an ascending grid).
-        """
-        if reduce == "mean":
-            obj = self.T.mean(axis=1)
-        elif reduce == "max":
-            obj = self.T.max(axis=1)
-        elif reduce == "final":
-            obj = self.T[:, -1]
-        else:
-            raise ValueError(f"unknown reduce {reduce!r}")
-        order = np.argsort(obj, kind="stable")
-        return [(self.names[i], float(obj[i])) for i in order]
-
-
-class MultiSweepEngine:
-    """DEPRECATED shim over :class:`repro.sweep.api.Engine` (graph axis).
-
-    Evaluate G packed graphs × S scenarios in one compiled program:
-
-    >>> eng = MultiSweepEngine([(v.graph, v.params) for v in variants],
-    ...                        names=[v.name for v in variants])
-    >>> res = eng.run(sweep.latency_grid(params, deltas))   # broadcast grid
-    >>> res.T.shape, res["algo=ring"].T.shape               # [G, S], [S]
-
-    Bit-identical to the unified engine (same jit cells); new code should
-    build ``Engine([plans...])`` directly — which also unlocks what this
-    class never supported: ``run(costs=)`` per-graph candidate axes and
-    sharding over any populated axis.
-    """
-
-    MAX_DENSE_BYTES = SweepEngine.MAX_DENSE_BYTES
-
-    def __init__(self, graphs_params=None, names=None,
-                 backend: str = "segment", shard=None,
-                 multi: Optional[MultiPlan] = None,
-                 cache: Optional[SweepCache] = DEFAULT_CACHE):
-        _warn_deprecated_shim("MultiSweepEngine")
-        from .api import Engine, ExecPolicy
-        pol = ExecPolicy(backend=backend, shard=shard, cache=cache)
-        if multi is None:
-            if not graphs_params:
-                raise ValueError("need (graph, params) pairs or a MultiPlan")
-            self._eng = Engine(list(graphs_params), policy=pol, names=names)
-            self.params = [p for _, p in graphs_params]
-        else:
-            self._eng = Engine(multi, policy=pol, names=names)
-            self.params = [None] * multi.G
-        # honor a subclass/class-level override of the dense-size guard
-        self._eng.MAX_DENSE_BYTES = type(self).MAX_DENSE_BYTES
-
-    @classmethod
-    def from_variants(cls, variants, **kw):
-        """Build from :class:`~repro.sweep.scenarios.GraphVariant`\\ s (which
-        must share one latency-class count — pre-group with
-        :func:`~repro.sweep.compile.group_plans` otherwise)."""
-        return cls([(v.graph, v.params) for v in variants],
-                   names=[v.name for v in variants], **kw)
-
-    # -- legacy attribute surface --------------------------------------------
-    @property
-    def multi(self) -> MultiPlan:
-        return self._eng.multi
-
-    @property
-    def names(self) -> tuple:
-        return self._eng.names
-
-    @names.setter
-    def names(self, value) -> None:
-        self._eng.names = tuple(value)
-
-    @property
-    def backend(self) -> str:
-        return self._eng.policy.backend
-
-    @property
-    def shard(self):
-        return self._eng.policy.shard
-
-    @property
-    def cache(self):
-        return self._eng.policy.cache
-
-    @property
-    def calls(self) -> int:
-        return self._eng.calls
-
-    def _arrays(self, kind: str):
-        return self._eng._arrays(kind)
-
-    def run(self, scenarios, compute_lam: bool = True,
-            backend: Optional[str] = None, shard=None,
-            use_cache: bool = True, costs=None):
-        """One compiled call → :class:`MultiSweepResult` over every graph.
-
-        ``scenarios``: one :class:`ScenarioBatch` (broadcast to all graphs)
-        or a per-graph sequence with equal S (variant studies whose base
-        parameter points differ).  ``backend="pallas"`` returns λ/ρ directly
-        (batched argmax kernel).  ``shard`` splits the MultiPlan's leading
-        graph axis across local devices via ``shard_map`` — the natural
-        mesh axis; results stay bit-identical to the single-device run.
-
-        ``costs`` (one cost batch / raw ``[K, ne]`` extras array per
-        graph) populates the candidate axis alongside the graph axis — a
-        capability the legacy engine never had; the result is then the
-        unified :class:`repro.sweep.api.Result` with ``T[G, K, S]``.
-        """
-        res = self._eng.run(scenarios=scenarios, compute_lam=compute_lam,
-                            backend=backend, shard=shard,
-                            use_cache=use_cache, costs=costs)
-        if "K" in res.axes:
-            return res
-        return MultiSweepResult(T=res.T, lam=res.lam, rho=res.rho,
-                                scenarios=res.scenarios, names=res.names,
-                                backend=res.backend,
-                                from_cache=res.from_cache)
-
-
 # -- lockstep-batched bisections (the dag.py loops, one engine call/round) ----
 
-def _probe(eng: SweepEngine, params: LogGPS, Lvals, cls: int,
+def _probe(eng: Engine, params: LogGPS, Lvals, cls: int,
            backend: Optional[str] = None):
     batch = latency_grid(params, np.asarray(Lvals, dtype=np.float64),
                          cls=cls, absolute=True)
-    res = eng.run(batch, compute_lam=True, use_cache=False, backend=backend)
+    res = eng.run(batch, outputs=("T", "lam"), use_cache=False,
+                  backend=backend)
     return res.T, res.lam[:, cls]
 
 
-def tolerance_batched(eng: SweepEngine, params: LogGPS,
+def tolerance_batched(eng: Engine, params: LogGPS,
                       degradations: Sequence[float], cls: int = 0,
                       L_hi: float = 1e7, tol: float = 1e-6,
                       max_iter: int = 200,
@@ -1594,7 +1275,7 @@ def tolerance_batched(eng: SweepEngine, params: LogGPS,
     return {float(p): float(v) for p, v in zip(degr, out)}
 
 
-def breakpoints_batched(eng: SweepEngine, params: LogGPS, L_min: float,
+def breakpoints_batched(eng: Engine, params: LogGPS, L_min: float,
                         L_max: float, cls: int = 0, tol: float = 1e-9,
                         max_bp: int = 10_000, max_depth: int = 80,
                         backend: Optional[str] = None) -> list:

@@ -12,7 +12,6 @@ stamped out as :class:`GraphVariant`s (reusing ``core.collectives`` /
 ``core.topology``); stack their compiled plans into a
 :class:`~repro.sweep.compile.StructureBatch` and run
 ``Query(structure=...)`` — one compiled program for the whole study.
-:func:`sweep_variants` remains as a deprecated shim over that path.
 """
 
 from __future__ import annotations
@@ -20,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import warnings
-from typing import Callable, Optional, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -455,96 +454,3 @@ def topology_variants(factory: Callable[[topo_mod.Topology, LogGPS],
                                 meta={"topology": t.name}))
     return out
 
-
-def sweep_variants(variants: Sequence[GraphVariant],
-                   batch_of: Callable[[GraphVariant], ScenarioBatch],
-                   backend: str = "segment", compute_lam: bool = True,
-                   batched: bool = True, max_inflation: float = 64.0,
-                   stats: Optional[dict] = None, cache="default") -> dict:
-    """DEPRECATED shim over the structure axis — run a variant study
-    through :class:`~repro.sweep.api.Engine` directly instead::
-
-        sb = StructureBatch.from_plans(plans, names=names)
-        res = Engine(sb).run(Query(scenarios=batch, structure=sb))
-
-    Returns {name: Result} (one :class:`~repro.sweep.api.Result` per
-    variant, scenario axis only — attribute-compatible with the legacy
-    per-variant ``SweepResult``).
-
-    ``batch_of(variant)`` builds the tensor-batchable sub-grid for that
-    variant (base points can differ per variant; latency-class counts can
-    differ across topologies).
-
-    With ``batched=True`` (default) variants are grouped into shape buckets
-    (:func:`~repro.sweep.compile.group_plans`: same class count, bounded
-    padding inflation), each bucket stacks into one
-    :class:`~repro.sweep.compile.StructureBatch` riding the engine's B
-    axis, and the study costs one compiled call per bucket × distinct
-    scenario grid — variants sharing a grid share a call.
-    ``batched=False`` restores the per-variant loop (one engine + call per
-    graph).
-
-    ``stats``, if given, is filled with {'groups': …, 'calls': …} so callers
-    can assert how many compiled dispatches the study cost.
-
-    ``cache``: a :class:`~repro.sweep.cache.SweepCache`, ``None`` to
-    disable result memoization (e.g. benchmarks that count compiled
-    dispatches), or the default shared cache.
-    """
-    import warnings
-    warnings.warn(
-        "sweep_variants() is deprecated: build a StructureBatch "
-        "(StructureBatch.from_plans / CompiledPlan.patch_structure) and "
-        "run Query(structure=...) on an Engine — same zero-recompile "
-        "batching, first-class B axis on the Result",
-        DeprecationWarning, stacklevel=2)
-    from .api import Engine, ExecPolicy  # avoid cycle
-    from .cache import DEFAULT_CACHE
-    from .compile import StructureBatch, compile_plan, group_plans
-
-    if cache == "default":
-        cache = DEFAULT_CACHE
-    policy = ExecPolicy(backend=backend, cache=cache)
-
-    if not batched:
-        out = {}
-        calls = 0
-        for v in variants:
-            eng = Engine(v.graph, params=v.params, policy=policy)
-            out[v.name] = eng.run(batch_of(v), compute_lam=compute_lam)
-            calls += eng.calls
-        if stats is not None:
-            stats.update(groups=len(variants), calls=calls)
-        return out
-
-    plans = [compile_plan(v.graph, v.params) for v in variants]
-    groups = group_plans(plans, max_inflation=max_inflation)
-    results: dict = {}
-    calls = 0
-    for idx in groups:
-        # the structure axis shares one scenario grid across its B
-        # variants, so sub-group the bucket by grid content (one call per
-        # distinct grid; identical batch_of outputs — the common case —
-        # keep the old one-call-per-bucket count)
-        batches = {i: batch_of(variants[i]) for i in idx}
-        subs: list = []
-        for i in idx:
-            key = (batches[i].L.tobytes(), batches[i].gscale.tobytes(),
-                   batches[i].L.shape)
-            for k2, members in subs:
-                if k2 == key:
-                    members.append(i)
-                    break
-            else:
-                subs.append((key, [i]))
-        for _, members in subs:
-            sb = StructureBatch.from_plans(
-                [plans[i] for i in members],
-                names=[variants[i].name for i in members])
-            eng = Engine(sb, policy=policy)
-            res = eng.run(batches[members[0]], compute_lam=compute_lam)
-            results.update(res.split())
-            calls += eng.calls
-    if stats is not None:
-        stats.update(groups=len(groups), calls=calls)
-    return {v.name: results[v.name] for v in variants}

@@ -43,6 +43,16 @@ def test_dense_phase_matches_oracle():
     assert recs[1]["tpu_custom_call"] is False       # interpreted on CPU
 
 
+def test_dense_phase_program_count_independent_of_history():
+    """The cold call's program count is its own: a second run of the
+    phase in one process, after every program it needs was built, still
+    counts one new program per backend."""
+    for _ in range(2):
+        recs = list(chip_smoke.phase_dense(TINY, platform="cpu"))
+        assert [(r["backend"], r["xla_programs"]) for r in recs] == [
+            ("segment", 1), ("pallas", 1)]
+
+
 def test_service_phase_replies_ok_without_fallbacks():
     recs = list(chip_smoke.phase_service(TINY, platform="cpu"))
     assert [r["kind"] for r in recs] == ["curve", "tolerance", "rank",

@@ -43,11 +43,6 @@ from repro.core import dag, lp, synth
 from repro.core.loggps import LogGPS, cluster_params, pod_model
 from repro import sweep
 
-# Shim coverage: this suite deliberately drives the deprecated
-# SweepEngine/MultiSweepEngine surface to pin the shims bit-identical to
-# the unified Engine — CI's -W error::DeprecationWarning is relaxed here.
-pytestmark = pytest.mark.filterwarnings("default::DeprecationWarning")
-
 BACKENDS = ("scalar", "segment", "pallas")
 OUTPUTS = ("T", "lam", "rho")
 PACKINGS = ("solo", "multi", "patched")
@@ -129,9 +124,9 @@ def computed():
     out = {}
     plans = {c.name: sweep.compile_plan(c.g, c.params) for c in CASES}
     for be in ("segment", "pallas"):
+        pol = sweep.ExecPolicy(backend=be, cache=None)
         for c in CASES:
-            eng = sweep.SweepEngine(compiled=plans[c.name], params=c.params,
-                                    backend=be, cache=None)
+            eng = sweep.Engine(plans[c.name], params=c.params, policy=pol)
             r = eng.run(c.batch)
             out[(be, "solo", c.name)] = {"T": r.T, "lam": r.lam, "rho": r.rho}
             rc = eng.run(c.batch, costs=plans[c.name].patch_costs(c.extras))
@@ -139,9 +134,9 @@ def computed():
                                             "rho": rc.rho}
         plan_list = [plans[c.name] for c in CASES]
         for idx in sweep.group_plans(plan_list):
-            meng = sweep.MultiSweepEngine(
-                multi=sweep.pack_plans([plan_list[i] for i in idx]),
-                names=[CASES[i].name for i in idx], backend=be, cache=None)
+            meng = sweep.Engine(
+                sweep.pack_plans([plan_list[i] for i in idx]),
+                names=[CASES[i].name for i in idx], policy=pol)
             res = meng.run([CASES[i].batch for i in idx])
             for j, i in enumerate(idx):
                 out[(be, "multi", CASES[i].name)] = {
@@ -227,15 +222,15 @@ def test_patched_bit_equal_rebuilt():
     for c in CASES:
         base = sweep.compile_plan(c.g, c.params)
         for be in ("segment", "pallas"):
-            eng = sweep.SweepEngine(compiled=base, params=c.params,
-                                    backend=be, cache=None)
+            pol = sweep.ExecPolicy(backend=be, cache=None)
+            eng = sweep.Engine(base, params=c.params, policy=pol)
             res = eng.run(c.batch, costs=base.patch_costs(c.extras))
             for k in range(K):
                 reb = sweep.compile_plan(c.g, c.params,
                                          extra_edge_cost=c.extras[k])
                 assert reb.shape_key == base.shape_key  # same XLA program
-                ref = sweep.SweepEngine(compiled=reb, params=c.params,
-                                        backend=be, cache=None).run(c.batch)
+                ref = sweep.Engine(reb, params=c.params,
+                                   policy=pol).run(c.batch)
                 np.testing.assert_array_equal(res.T[k], ref.T,
                                               err_msg=f"{c.name}/{be}")
                 np.testing.assert_array_equal(res.lam[k], ref.lam,
@@ -254,12 +249,11 @@ def test_with_extra_cost_shares_structure():
     assert patched.shape_key == base.shape_key
     assert patched.vsrc is base.vsrc and patched.emask is base.emask
     assert patched.content_hash() != base.content_hash()
-    a = sweep.SweepEngine(compiled=patched, params=c.params, cache=None) \
-        .run(c.batch)
-    b = sweep.SweepEngine(
-        compiled=sweep.compile_plan(c.g, c.params,
-                                    extra_edge_cost=c.extras[0]),
-        params=c.params, cache=None).run(c.batch)
+    pol = sweep.ExecPolicy(cache=None)
+    a = sweep.Engine(patched, params=c.params, policy=pol).run(c.batch)
+    b = sweep.Engine(
+        sweep.compile_plan(c.g, c.params, extra_edge_cost=c.extras[0]),
+        params=c.params, policy=pol).run(c.batch)
     np.testing.assert_array_equal(a.T, b.T)
     np.testing.assert_array_equal(a.lam, b.lam)
 
@@ -276,7 +270,7 @@ def test_random_graph_matrix():
                    o=float(rng.uniform(0.0, 4.0)), S=1e9)
         g = synth.random_dag(rng, nranks=int(rng.integers(2, 5)), nops=40,
                              p_msg=float(rng.uniform(0.2, 0.6)), params=p)
-        eng = sweep.SweepEngine(g, p, cache=None)
+        eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
         deltas = np.sort(rng.uniform(0.0, 60.0, size=4))
         batch = sweep.latency_grid(p, deltas)
         seg = eng.run(batch)
@@ -369,7 +363,7 @@ def test_lambda_matches_highs_marginals():
     marginals) from the explicit HiGHS LP (absorbed from test_sweep)."""
     p = cluster_params(L_us=3.0, o_us=5.0)
     g = synth.stencil2d(3, 3, 3, params=p)
-    eng = sweep.SweepEngine(g, p, cache=None)
+    eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
     for dL in (0.0, 10.0):
         pt = p.with_delta(dL)
         res = eng.run(sweep.base_batch(pt))
@@ -384,7 +378,8 @@ def test_rejections():
     edge-position records."""
     c = CASES[0]
     base = sweep.compile_plan(c.g, c.params)
-    eng = sweep.SweepEngine(compiled=base, params=c.params, cache=None)
+    eng = sweep.Engine(base, params=c.params,
+                       policy=sweep.ExecPolicy(cache=None))
     with pytest.raises(ValueError, match="backend"):
         eng.run(c.batch, backend="cuda")
     with pytest.raises(ValueError, match="edges"):
@@ -444,21 +439,20 @@ def _bucketable_cases():
 
 @pytest.fixture(scope="module")
 def unified_ref():
-    """Legacy-path references: per (case, k) a SOLO run of a plan REBUILT
-    with cost block k (the equivalent legacy solo/rebuild runs every
-    populated-axis combination must reproduce), per backend."""
+    """Solo references: per (case, k) a SOLO run of a plan REBUILT with
+    cost block k (the solo/rebuild runs every populated-axis combination
+    must reproduce), per backend."""
     ref = {}
     for c in _bucketable_cases():
         for be in ("segment", "pallas"):
-            solo = sweep.SweepEngine(c.g, c.params, backend=be,
-                                     cache=None).run(c.batch)
+            pol = sweep.ExecPolicy(backend=be, cache=None)
+            solo = sweep.Engine(c.g, params=c.params, policy=pol).run(c.batch)
             ref[(c.name, be, None)] = solo
             for k in range(K):
                 reb = sweep.compile_plan(c.g, c.params,
                                          extra_edge_cost=c.extras[k])
-                ref[(c.name, be, k)] = sweep.SweepEngine(
-                    compiled=reb, params=c.params, backend=be,
-                    cache=None).run(c.batch)
+                ref[(c.name, be, k)] = sweep.Engine(
+                    reb, params=c.params, policy=pol).run(c.batch)
     return ref
 
 
@@ -466,10 +460,10 @@ def unified_ref():
 @pytest.mark.parametrize("backend", ("segment", "pallas"))
 def test_unified_axis_matrix(backend, axisset, unified_ref):
     """Every populated-axis combination of the unified Engine against the
-    equivalent legacy-path runs: segment rows bit-equal, pallas ≤1e-5
-    relative — T, λ and ρ alike.  The G×K×S cell is the combination NO
-    legacy engine supported (per-graph candidate axes on a packed graph
-    axis); its reference is the cartesian product of solo rebuild runs."""
+    equivalent solo/rebuild runs: segment rows bit-equal, pallas ≤1e-5
+    relative — T, λ and ρ alike.  The G×K×S cell (per-graph candidate
+    axes on a packed graph axis) has as its reference the cartesian
+    product of solo rebuild runs."""
     cases = _bucketable_cases()
     pol = sweep.ExecPolicy(backend=backend, cache=None)
     has_G, has_K = "G" in axisset, "K" in axisset
@@ -644,33 +638,6 @@ def test_unified_engine_shards_any_axis():
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, env=env, timeout=600)
     assert res.returncode == 0 and res.stdout.strip() == "OK", res.stderr
-
-
-def test_shims_bit_identical_to_engine():
-    """The deprecation contract: SweepEngine/MultiSweepEngine delegate to
-    the unified Engine and stay bit-identical — and they DO warn."""
-    c = CASES[0]
-    with pytest.warns(DeprecationWarning, match="SweepEngine is deprecated"):
-        leg = sweep.SweepEngine(c.g, c.params, cache=None)
-    new = sweep.Engine(c.g, params=c.params,
-                       policy=sweep.ExecPolicy(cache=None))
-    a, b = leg.run(c.batch), new.run(c.batch)
-    np.testing.assert_array_equal(a.T, b.T)
-    np.testing.assert_array_equal(a.lam, b.lam)
-    np.testing.assert_array_equal(a.rho, b.rho)
-    cases = _bucketable_cases()
-    with pytest.warns(DeprecationWarning,
-                      match="MultiSweepEngine is deprecated"):
-        mleg = sweep.MultiSweepEngine([(x.g, x.params) for x in cases],
-                                      names=[x.name for x in cases],
-                                      cache=None)
-    mnew = sweep.Engine([(x.g, x.params) for x in cases],
-                        names=[x.name for x in cases],
-                        policy=sweep.ExecPolicy(cache=None))
-    ma = mleg.run([x.batch for x in cases])
-    mb = mnew.run([x.batch for x in cases])
-    np.testing.assert_array_equal(ma.T, mb.T)
-    np.testing.assert_array_equal(ma.lam, mb.lam)
 
 
 def test_zero_congestion_fixed_point_bit_identical():

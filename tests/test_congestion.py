@@ -23,7 +23,7 @@ from repro import obs, sweep
 from repro.core import sensitivity, simulator, synth
 from repro.core.graph import GraphBuilder, edge_gap_shares
 from repro.core.loggps import (NetClass, NetworkModel, cluster_params,
-                               pod_model, resolve_class, tpu_pod_params)
+                               pod_model, resolve_class)
 from repro.launch.analysis import (AnalysisRequest, AnalysisService)
 from repro.sweep.api import Engine, ExecPolicy
 
@@ -69,19 +69,6 @@ def test_netclass_from_gbps():
     p = m.params()
     assert p.class_names == ("ici", "dcn")
     assert p.L == (1.0, 10.0)
-
-
-def test_tpu_pod_params_shim_bit_identical():
-    """The deprecation contract: the legacy constructor warns and returns
-    params numerically identical to the registry path."""
-    with pytest.warns(DeprecationWarning, match="tpu_pod_params"):
-        old = tpu_pod_params(pod_size=2)
-    new = pod_model(pod_size=2).params()
-    assert old.L == new.L and old.G == new.G
-    assert old.o == new.o and old.S == new.S
-    for a in range(4):
-        for b in range(4):
-            assert old.link_class(a, b) == new.link_class(a, b)
 
 
 # -- link interning (the physical-link axis congestion aggregates over) -------

@@ -329,22 +329,3 @@ def test_byte_accounting_and_gauge(fixture):
     assert gauge.value(view="segment") == float(dense_b)
     fresh.run(batch, backend="sparse")
     assert gauge.value(view="sparse") == float(sp.sparse_bytes())
-
-
-def test_sweep_variants_shim_is_thin(params):
-    """The deprecated sweep_variants batched path ≡ a hand-built
-    Query(structure=) run, bit for bit — it IS that call now."""
-    variants = sweep.collective_variants(
-        lambda a: synth.allreduce_chain(8, 2, params=params, algo=a),
-        ["ring", "recursive_doubling", "tree"], params)
-    batch = sweep.latency_grid(params, np.linspace(0.0, 30.0, 5))
-    with pytest.warns(DeprecationWarning, match="StructureBatch"):
-        out = sweep.sweep_variants(variants, lambda v: batch, cache=None)
-    plans = [sweep.compile_plan(v.graph, v.params) for v in variants]
-    sb = sweep.StructureBatch.from_plans(
-        plans, names=[v.name for v in variants])
-    res = sweep.Engine(sb, policy=sweep.ExecPolicy(cache=None)) \
-        .run(sweep.Query(scenarios=batch))
-    for i, v in enumerate(variants):
-        np.testing.assert_array_equal(out[v.name].T, res.T[i])
-        np.testing.assert_array_equal(out[v.name].lam, res.lam[i])

@@ -12,16 +12,10 @@ import pytest
 pytest.importorskip("jax")
 
 from repro.core import dag, sensitivity, synth
-from repro.core.loggps import cluster_params, tpu_pod_params
+from repro.core.loggps import cluster_params, pod_model
 from repro import sweep
 from repro.sweep import cache as sweep_cache
 from repro.sweep import engine as sweep_engine
-
-# Shim coverage: this file deliberately exercises the deprecated
-# SweepEngine/MultiSweepEngine surface (feature regressions must keep
-# passing on the legacy entry points) — CI's -W error::DeprecationWarning
-# is relaxed for it.
-pytestmark = pytest.mark.filterwarnings("default::DeprecationWarning")
 
 
 @pytest.fixture(scope="module")
@@ -29,10 +23,34 @@ def params():
     return cluster_params(L_us=3.0, o_us=5.0)
 
 
+def _variant_engine(variants, cache):
+    """One multi-graph Engine: the variants' graphs on the G axis, under
+    the variants' names."""
+    return sweep.Engine([(v.graph, v.params) for v in variants],
+                        names=[v.name for v in variants],
+                        policy=sweep.ExecPolicy(cache=cache))
+
+
+def _structure_study(variants, batch):
+    """A variant study on the structure axis: one StructureBatch per
+    ``group_plans`` bucket, one Engine call each.  Returns
+    ({name: Result}, bucket count, compiled calls)."""
+    plans = [sweep.compile_plan(v.graph, v.params) for v in variants]
+    groups = sweep.group_plans(plans)
+    out, calls = {}, 0
+    for idx in groups:
+        sb = sweep.StructureBatch.from_plans(
+            [plans[i] for i in idx], names=[variants[i].name for i in idx])
+        eng = sweep.Engine(sb, policy=sweep.ExecPolicy(cache=None))
+        out.update(eng.run(batch).split())
+        calls += eng.calls
+    return out, len(groups), calls
+
+
 def test_bandwidth_scenarios_match_rebuilt_graph(params):
     """γ·G scenarios ≡ rebuilding the graph with scaled G (exact gap split)."""
     g = synth.cg_like(2, 2, 3, params=params)
-    eng = sweep.SweepEngine(g, params)
+    eng = sweep.Engine(g, params=params)
     res = eng.run(sweep.bandwidth_grid(params, [1.0, 2.0, 4.0]))
     for i, gs in enumerate([1.0, 2.0, 4.0]):
         p2 = params.replace(G=tuple(gs * x for x in params.G))
@@ -47,7 +65,7 @@ def test_cartesian_grid_shapes(params):
     assert batch.S == 6
     assert batch.meta[0] == {"dL[0]": 0.0, "gscale[0]": 1.0}
     g = synth.stencil2d(2, 2, 2, params=params)
-    res = sweep.SweepEngine(g, params).run(batch)
+    res = sweep.Engine(g, params=params).run(batch)
     assert res.T.shape == (6,)
     # T monotone in both ΔL and γ
     assert res.T[1] >= res.T[0] and res.T[5] >= res.T[4]
@@ -72,9 +90,8 @@ def test_collective_variants(params):
     variants = sweep.collective_variants(
         lambda a: synth.allreduce_chain(8, 2, params=params, algo=a),
         ["ring", "recursive_doubling"], params)
-    with pytest.warns(DeprecationWarning, match="StructureBatch"):
-        out = sweep.sweep_variants(
-            variants, lambda v: sweep.latency_grid(params, [0.0, 20.0]))
+    out, _, _ = _structure_study(variants,
+                                 sweep.latency_grid(params, [0.0, 20.0]))
     # recursive doubling has fewer latency-critical rounds: λ smaller, and
     # under +20µs latency it beats ring (the Fig 10 ordering)
     ring, rd = out["algo=ring"], out["algo=recursive_doubling"]
@@ -85,7 +102,7 @@ def test_collective_variants(params):
 def test_tolerance_batched_matches_scalar(params):
     g = synth.stencil2d(3, 3, 4, params=params)
     degr = (0.01, 0.02, 0.05, 0.1)
-    eng = sweep.SweepEngine(g, params)
+    eng = sweep.Engine(g, params=params)
     batched = sweep_engine.tolerance_batched(eng, params, degr)
     for p_ in degr:
         ref = dag.tolerance(g, params, p_)
@@ -94,7 +111,7 @@ def test_tolerance_batched_matches_scalar(params):
 
 def test_breakpoints_batched_matches_scalar(params):
     g = synth.sweep2d(3, 3, 3, params=params)
-    eng = sweep.SweepEngine(g, params)
+    eng = sweep.Engine(g, params=params)
     batched = sweep_engine.breakpoints_batched(eng, params, 0.5, 500.0)
     ref = dag.breakpoints(g, params, 0.5, 500.0)
     assert len(batched) == len(ref)
@@ -127,7 +144,7 @@ def test_sensitivity_dispatch_equivalence(params):
 def test_result_cache(params):
     g = synth.stencil2d(2, 2, 2, params=params)
     cache = sweep_cache.SweepCache(capacity=8)
-    eng = sweep.SweepEngine(g, params, cache=cache)
+    eng = sweep.Engine(g, params=params, policy=sweep.ExecPolicy(cache=cache))
     batch = sweep.latency_grid(params, [0.0, 5.0, 10.0])
     r1 = eng.run(batch)
     assert not r1.from_cache and cache.stats.hits == 0
@@ -142,7 +159,8 @@ def test_result_cache(params):
     np.testing.assert_array_equal(eng.run(batch).T, ref)
     # structurally identical graph, fresh engine → same content hash → hit
     g2 = synth.stencil2d(2, 2, 2, params=params)
-    eng2 = sweep.SweepEngine(g2, params, cache=cache)
+    eng2 = sweep.Engine(g2, params=params,
+                        policy=sweep.ExecPolicy(cache=cache))
     r3 = eng2.run(batch)
     assert r3.from_cache
     # different scenarios miss
@@ -163,8 +181,8 @@ def test_compiled_plan_bucketing(params):
 
 def test_engine_rejects_mismatched_classes(params):
     g = synth.stencil2d(2, 2, 2, params=params)
-    eng = sweep.SweepEngine(g, params)
-    two_cls = tpu_pod_params(pod_size=2)
+    eng = sweep.Engine(g, params=params)
+    two_cls = pod_model(pod_size=2).params()
     with pytest.raises(ValueError, match="classes"):
         eng.run(sweep.latency_grid(two_cls, [0.0, 1.0]))
     with pytest.raises(ValueError, match="engine"):
@@ -176,10 +194,9 @@ def test_engine_rejects_mismatched_classes(params):
 def _collective_topology_variants():
     """3 collective algorithms × 2 two-class topologies = 6 GraphVariants
     sharing one latency-class count (so they can pack)."""
-    from repro.core.loggps import tpu_pod_params
     out = []
     for pod, tag in ((2, "pod2"), (4, "pod4")):
-        p = tpu_pod_params(pod_size=pod)
+        p = pod_model(pod_size=pod).params()
         for algo in ("ring", "recursive_doubling", "tree"):
             g = synth.allreduce_chain(8, 2, params=p, algo=algo)
             out.append(sweep.GraphVariant(name=f"{tag}/{algo}", graph=g,
@@ -192,7 +209,7 @@ def test_multiplan_getitem_by_index_and_name():
     """__getitem__ by index and by name give the same slice (the packed ≡
     solo value equivalence itself lives in the conformance matrix)."""
     variants = _collective_topology_variants()[:2]
-    meng = sweep.MultiSweepEngine.from_variants(variants, cache=None)
+    meng = _variant_engine(variants, cache=None)
     res = meng.run(sweep.latency_grid(variants[0].params, [0.0, 10.0]))
     for i, v in enumerate(variants):
         np.testing.assert_array_equal(res[i].T, res[v.name].T)
@@ -204,10 +221,11 @@ def test_multiplan_repad_is_exact(params):
     g = synth.stencil2d(3, 3, 3, params=params)
     c = sweep.compile_plan(g, params)
     grid = sweep.latency_grid(params, np.linspace(0.0, 40.0, 7))
-    base = sweep.SweepEngine(compiled=c, params=params, cache=None).run(grid)
+    pol = sweep.ExecPolicy(cache=None)
+    base = sweep.Engine(c, params=params, policy=pol).run(grid)
     nlv, V, D = c.vsrc.shape
     big = repad_plan(c, nlv * 2, V * 2, D * 2, c.esrc.shape[1] * 2)
-    res = sweep.SweepEngine(compiled=big, params=params, cache=None).run(grid)
+    res = sweep.Engine(big, params=params, policy=pol).run(grid)
     np.testing.assert_array_equal(res.T, base.T)
     np.testing.assert_array_equal(res.lam, base.lam)
     with pytest.raises(ValueError, match="smaller"):
@@ -215,7 +233,6 @@ def test_multiplan_repad_is_exact(params):
 
 
 def test_group_plans_buckets_and_inflation(params):
-    from repro.core.loggps import tpu_pod_params
     small = sweep.compile_plan(synth.stencil2d(2, 2, 2, params=params), params)
     huge = sweep.compile_plan(synth.allreduce_chain(16, 6, params=params),
                               params)
@@ -225,7 +242,7 @@ def test_group_plans_buckets_and_inflation(params):
     # everything fits one bucket when the bound is loose
     assert sweep.group_plans([small, small], max_inflation=64.0) == [[0, 1]]
     # different latency-class counts never pack
-    p2 = tpu_pod_params(pod_size=2)
+    p2 = pod_model(pod_size=2).params()
     two = sweep.compile_plan(synth.stencil2d(2, 2, 2, params=p2), p2)
     assert sweep.group_plans([small, two]) == [[0], [1]]
     with pytest.raises(ValueError, match="class"):
@@ -237,18 +254,17 @@ def test_sweep_variants_batched_call_count(params):
     variants = sweep.collective_variants(
         lambda a: synth.allreduce_chain(8, 1, params=params, algo=a),
         ["ring", "bidir_ring", "recursive_doubling", "tree"], params)
-    batch_of = lambda v: sweep.latency_grid(params, np.linspace(0, 50, 20))
-    stats = {}
-    with pytest.warns(DeprecationWarning, match="StructureBatch"):
-        batched = sweep.sweep_variants(variants, batch_of, stats=stats,
-                                       batched=True, cache=None)
-    assert stats["groups"] < len(variants)      # buckets merged variants
-    assert stats["calls"] == stats["groups"] <= len(variants)
-    loop_stats = {}
-    with pytest.warns(DeprecationWarning, match="StructureBatch"):
-        loop = sweep.sweep_variants(variants, batch_of, stats=loop_stats,
-                                    batched=False, cache=None)
-    assert loop_stats["calls"] == len(variants)
+    batch = sweep.latency_grid(params, np.linspace(0, 50, 20))
+    batched, groups, calls = _structure_study(variants, batch)
+    assert groups < len(variants)               # buckets merged variants
+    assert calls == groups <= len(variants)
+    loop, loop_calls = {}, 0
+    for v in variants:
+        eng = sweep.Engine(v.graph, params=v.params,
+                           policy=sweep.ExecPolicy(cache=None))
+        loop[v.name] = eng.run(batch)
+        loop_calls += eng.calls
+    assert loop_calls == len(variants)
     for name, ref in loop.items():
         np.testing.assert_array_equal(batched[name].T, ref.T)
         np.testing.assert_array_equal(batched[name].lam, ref.lam)
@@ -258,7 +274,7 @@ def test_multisweep_rank_and_broadcast(params):
     variants = sweep.collective_variants(
         lambda a: synth.allreduce_chain(8, 2, params=params, algo=a),
         ["ring", "recursive_doubling"], params)
-    meng = sweep.MultiSweepEngine.from_variants(variants, cache=None)
+    meng = _variant_engine(variants, cache=None)
     # one ScenarioBatch broadcasts to every graph
     res = meng.run(sweep.latency_grid(params, np.linspace(0, 40, 10)))
     order = res.rank(reduce="final")
@@ -275,7 +291,7 @@ def test_multisweep_result_cache(params):
         lambda a: synth.allreduce_chain(8, 1, params=params, algo=a),
         ["ring", "tree"], params)
     cache = sweep_cache.SweepCache(capacity=4)
-    meng = sweep.MultiSweepEngine.from_variants(variants, cache=cache)
+    meng = _variant_engine(variants, cache=cache)
     grid = sweep.latency_grid(params, [0.0, 10.0, 20.0])
     r1 = meng.run(grid)
     assert not r1.from_cache
@@ -288,7 +304,7 @@ def test_multisweep_result_cache(params):
     np.testing.assert_array_equal(meng.run(grid).T, ref)
     # a different engine over the same plans hits content-addressed — but
     # the result must carry THAT engine's names, not the cached ones
-    meng2 = sweep.MultiSweepEngine.from_variants(variants, cache=cache)
+    meng2 = _variant_engine(variants, cache=cache)
     meng2.names = ("renamed_ring", "renamed_tree")
     r3 = meng2.run(grid)
     assert r3.from_cache and r3.names == ("renamed_ring", "renamed_tree")
@@ -305,8 +321,8 @@ def test_gap_shares_survive_params_drift(params):
     assert g.egap is not None and g.egclass is not None
     assert float(g.egap.sum()) > 0
     drifted = params.replace(G=tuple(7.0 * x for x in params.G))
-    eng = sweep.SweepEngine(compiled=sweep.compile_plan(g, drifted),
-                            params=params, cache=None)
+    eng = sweep.Engine(sweep.compile_plan(g, drifted), params=params,
+                       policy=sweep.ExecPolicy(cache=None))
     res = eng.run(sweep.bandwidth_grid(params, [1.0, 2.0, 4.0]))
     for i, gs in enumerate([1.0, 2.0, 4.0]):
         p2 = params.replace(G=tuple(gs * x for x in params.G))
@@ -329,7 +345,7 @@ def test_gap_shares_on_traced_graphs():
     p = ts.params()
     curve = sensitivity.bandwidth_curve(g, p, [1.0, 3.0], engine="scalar")
     assert curve.T[1] > curve.T[0]      # slower links ⇒ longer step
-    eng = sweep.SweepEngine(g, p, cache=None)
+    eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
     res = eng.run(sweep.bandwidth_grid(p, [1.0, 3.0]))
     np.testing.assert_allclose(res.T, curve.T, rtol=1e-9)
 
@@ -367,7 +383,7 @@ def test_gap_reconstruction_backstops_raw_add_edge(params):
     g = build(params)
     # the raw message edge recorded NaN = "share unknown", not a zero
     assert np.isnan(g.egap[g.ebytes > 0]).all()
-    eng = sweep.SweepEngine(g, params, cache=None)
+    eng = sweep.Engine(g, params=params, policy=sweep.ExecPolicy(cache=None))
     res = eng.run(sweep.bandwidth_grid(params, [1.0, 3.0]))
     for i, gs in enumerate([1.0, 3.0]):
         p2 = params.replace(G=tuple(gs * x for x in params.G))
@@ -434,7 +450,7 @@ def test_canonical_bytes_disambiguates_layouts():
 def test_cache_eviction_and_stats(params):
     cache = sweep_cache.SweepCache(capacity=2)
     g = synth.stencil2d(2, 2, 2, params=params)
-    eng = sweep.SweepEngine(g, params, cache=cache)
+    eng = sweep.Engine(g, params=params, policy=sweep.ExecPolicy(cache=cache))
     grids = [sweep.latency_grid(params, [float(k)]) for k in range(3)]
     for b in grids:
         eng.run(b)
@@ -462,12 +478,12 @@ def test_two_pass_lambda_bit_identical_to_fused(params):
     tie-heavy collective graphs and multi-class params included."""
     import jax
     import jax.numpy as jnp
-    p2 = tpu_pod_params(pod_size=2)
+    p2 = pod_model(pod_size=2).params()
     cases = [(synth.allreduce_chain(8, 3, params=params), params),
              (synth.stencil2d(3, 3, 4, params=params), params),
              (synth.stencil2d(2, 2, 3, params=p2), p2)]
     for g, p in cases:
-        eng = sweep.SweepEngine(g, p, cache=None)
+        eng = sweep.Engine(g, params=p, policy=sweep.ExecPolicy(cache=None))
         grid = sweep.latency_grid(p, np.linspace(0.0, 60.0, 9))
         res = eng.run(grid)                        # two-pass default
         S = grid.S
@@ -503,14 +519,16 @@ def test_sharded_matches_single_device():
         "variants = sweep.collective_variants(\n"
         "    lambda a: synth.allreduce_chain(8, 1, params=p, algo=a),\n"
         "    ['ring', 'recursive_doubling'], p)\n"
-        "meng = sweep.MultiSweepEngine.from_variants(variants, cache=None)\n"
+        "pol = sweep.ExecPolicy(cache=None)\n"
+        "meng = sweep.Engine([(v.graph, v.params) for v in variants],\n"
+        "                    names=[v.name for v in variants], policy=pol)\n"
         "grid = sweep.latency_grid(p, np.linspace(0.0, 40.0, 8))\n"
         "base = meng.run(grid)\n"
         "sh = meng.run(grid, shard=True)\n"
         "assert np.array_equal(base.T, sh.T)\n"
         "assert np.array_equal(base.lam, sh.lam)\n"
         "g = synth.stencil2d(2, 2, 3, params=p)\n"
-        "eng = sweep.SweepEngine(g, p, cache=None)\n"
+        "eng = sweep.Engine(g, params=p, policy=pol)\n"
         "b = eng.run(grid)\n"
         "s = eng.run(grid, shard=True)\n"
         "assert np.array_equal(b.T, s.T) and np.array_equal(b.lam, s.lam)\n"
@@ -565,7 +583,7 @@ def test_auto_dispatch_warns_once_then_falls_back(params, monkeypatch):
     def boom(self, *a, **k):
         raise RuntimeError("injected engine failure")
 
-    monkeypatch.setattr(sweep.SweepEngine, "run", boom)
+    monkeypatch.setattr(sweep.Engine, "run", boom)
     with warnings_mod.catch_warnings():
         warnings_mod.simplefilter("error", RuntimeWarning)
         for _ in range(2):                       # every call, not just once
@@ -585,7 +603,7 @@ def test_auto_dispatch_survives_engine_construction_failure(params,
     def boom(self, *a, **k):
         raise RuntimeError("injected construction failure")
 
-    monkeypatch.setattr(sweep.SweepEngine, "__init__", boom)
+    monkeypatch.setattr(sweep.Engine, "__init__", boom)
     with pytest.raises(RuntimeError, match="injected construction failure"):
         sensitivity.latency_curve(g, params, deltas)
     with pytest.raises(RuntimeError, match="injected construction"):
@@ -605,7 +623,7 @@ def test_auto_dispatch_scalar_only_without_jax(params, monkeypatch):
     def no_jax(self, *a, **k):
         raise ModuleNotFoundError("No module named 'jax'", name="jax")
 
-    monkeypatch.setattr(sweep.SweepEngine, "run", no_jax)
+    monkeypatch.setattr(sweep.Engine, "run", no_jax)
     auto = sensitivity.latency_curve(g, params, deltas)
     np.testing.assert_array_equal(auto.T, ref.T)
     np.testing.assert_array_equal(auto.lam, ref.lam)
@@ -633,7 +651,7 @@ def test_auto_dispatch_raises_on_other_import_errors(params, monkeypatch):
         def broken(self, *a, _err=err, **k):
             raise _err
 
-        monkeypatch.setattr(sweep.SweepEngine, "run", broken)
+        monkeypatch.setattr(sweep.Engine, "run", broken)
         with pytest.raises(ImportError):
             sensitivity.latency_curve(g, params, deltas)
 
@@ -663,7 +681,7 @@ def test_pallas_lam_override_warns_once(params, monkeypatch):
     """If the argmax kernel can't be built, an explicit backend='pallas'
     λ request raises — it is never re-routed to segment."""
     g = synth.stencil2d(2, 2, 2, params=params)
-    eng = sweep.SweepEngine(g, params, cache=None)
+    eng = sweep.Engine(g, params=params, policy=sweep.ExecPolicy(cache=None))
     batch = sweep.latency_grid(params, [0.0, 5.0])
 
     real = sweep_engine._get_forward
@@ -677,10 +695,9 @@ def test_pallas_lam_override_warns_once(params, monkeypatch):
     monkeypatch.setattr(sweep_engine, "_get_forward", fake)
     for _ in range(2):
         with pytest.raises(ImportError, match="no argmax kernel"):
-            eng.run(batch, backend="pallas", compute_lam=True,
-                    use_cache=False)
+            eng.run(batch, backend="pallas", use_cache=False)
     # the values-only pallas program is unaffected
-    res = eng.run(batch, backend="pallas", compute_lam=False)
+    res = eng.run(batch, backend="pallas", outputs=("T",))
     assert res.backend == "pallas"
 
 
@@ -688,16 +705,16 @@ def test_sensitivity_memo_key_is_content_based():
     """Regression for the id(rank_of_class) memo key: logically-equal
     params built twice (distinct callables, same class mapping) share one
     compiled engine; a different mapping gets its own."""
-    p1 = tpu_pod_params(pod_size=2)
+    p1 = pod_model(pod_size=2).params()
     g = synth.stencil2d(2, 2, 2, params=p1)
     deltas = np.linspace(0.0, 10.0, 10)
     sensitivity.latency_curve(g, p1, deltas, cls=1)
-    p2 = tpu_pod_params(pod_size=2)              # fresh, content-equal
+    p2 = pod_model(pod_size=2).params()          # fresh, content-equal
     assert p2.rank_of_class is not p1.rank_of_class
     sensitivity.latency_curve(g, p2, deltas, cls=1)
     memo = getattr(g, "_sweep_engines")
     assert len(memo) == 1, "content-equal params must share one engine"
-    p3 = tpu_pod_params(pod_size=4)              # different class mapping
+    p3 = pod_model(pod_size=4).params()          # different class mapping
     sensitivity.latency_curve(g, p3, deltas, cls=1)
     assert len(memo) == 2
 
@@ -712,6 +729,46 @@ def test_sensitivity_memoizes_engine(params):
     eng = next(iter(memo.values()))
     sensitivity.latency_curve(g, params, deltas)
     assert next(iter(memo.values())) is eng
+
+
+def test_curve_and_resilience_share_one_engine(params):
+    """The default path (``policy=None``) runs under ``ExecPolicy()`` and
+    shares its memoized engine with an explicit default policy: a curve
+    and then a resilience report on one graph compile one plan."""
+    g = synth.cg_like(2, 2, 3, params=params)
+    sensitivity.latency_curve(g, params, np.linspace(0.0, 10.0, 10))
+    sensitivity.resilience_curve(
+        g, params, [sweep.LinkFault(cls=0, extra_L_us=20.0)])
+    assert len(getattr(g, "_sweep_engines")) == 1
+
+
+@pytest.mark.parametrize("fn", ["latency_curve", "latency_tolerance",
+                                "critical_latencies"])
+def test_default_path_bit_identical_to_explicit_engine(params, fn):
+    """The sensitivity API's default batched path is an
+    ``Engine(g, params=p, policy=ExecPolicy())`` and nothing more: bit for
+    bit the same answers, and no warning on the way."""
+    import warnings as warnings_mod
+    g = synth.cg_like(2, 2, 3, params=params)
+    eng = sweep.Engine(g, params=params, policy=sweep.ExecPolicy(cache=None))
+    with warnings_mod.catch_warnings():
+        warnings_mod.simplefilter("error")
+        if fn == "latency_curve":
+            deltas = np.linspace(0.0, 100.0, 10)
+            got = sensitivity.latency_curve(g, params, deltas)
+            ref = eng.run(sweep.latency_grid(params, deltas))
+            np.testing.assert_array_equal(got.T, ref.T)
+            np.testing.assert_array_equal(got.lam, ref.lam[:, 0])
+            np.testing.assert_array_equal(got.rho, ref.rho[:, 0])
+        elif fn == "latency_tolerance":
+            degr = (0.01, 0.02, 0.05, 0.1)
+            assert sensitivity.latency_tolerance(g, params, degr) \
+                == sweep.tolerance_batched(eng, params, degr)
+        else:
+            # engine="sweep": the batched path on a graph this small
+            assert sensitivity.critical_latencies(
+                g, params, 0.5, 300.0, engine="sweep") \
+                == sweep.breakpoints_batched(eng, params, 0.5, 300.0)
 
 
 def test_multisweep_override_warns_once_per_engine_instance(params,
@@ -737,15 +794,15 @@ def test_multisweep_override_warns_once_per_engine_instance(params,
     g = synth.stencil2d(2, 2, 2, params=params)
     with warnings_mod.catch_warnings():
         warnings_mod.simplefilter("error", RuntimeWarning)
-        for make in (lambda: sweep.MultiSweepEngine.from_variants(
-                         variants, cache=None),
-                     lambda: sweep.SweepEngine(g, params, cache=None)):
+        for make in (lambda: _variant_engine(variants, cache=None),
+                     lambda: sweep.Engine(
+                         g, params=params,
+                         policy=sweep.ExecPolicy(cache=None))):
             for _ in range(2):                   # fresh instance, twice each
                 eng = make()
                 for _ in range(2):
                     with pytest.raises(ImportError, match="no argmax"):
-                        eng.run(grid, backend="pallas", compute_lam=True,
-                                use_cache=False)
+                        eng.run(grid, backend="pallas", use_cache=False)
 
 
 def test_cache_patched_cost_stats_and_eviction(params):
@@ -755,7 +812,8 @@ def test_cache_patched_cost_stats_and_eviction(params):
     g = synth.stencil2d(2, 2, 2, params=params)
     base = sweep.compile_plan(g, params)
     cache = sweep_cache.SweepCache(capacity=2)
-    eng = sweep.SweepEngine(compiled=base, params=params, cache=cache)
+    eng = sweep.Engine(base, params=params,
+                       policy=sweep.ExecPolicy(cache=cache))
     batch = sweep.latency_grid(params, [0.0, 5.0])
     rng = np.random.default_rng(3)
     exs = [np.where(g.ebytes > 0, rng.uniform(0.0, 5.0, g.num_edges), 0.0)
@@ -849,16 +907,3 @@ def test_placement_patch_stats_and_cache(params):
     placement.place(g, phi, params=zero, pi0=pi0.copy(), cache=cache)
     assert cache.stats.patched_hits >= cache.stats.patched_misses
 
-
-def test_shim_forwards_max_dense_bytes(params):
-    """A class-level MAX_DENSE_BYTES override on the legacy shim must
-    reach the unified engine's pallas dense-size guard."""
-    g = synth.stencil2d(2, 2, 2, params=params)
-
-    class TinyEngine(sweep.SweepEngine):
-        MAX_DENSE_BYTES = 1            # nothing fits
-
-    eng = TinyEngine(g, params, cache=None)
-    with pytest.raises(ValueError, match="dense pallas backend"):
-        eng.run(sweep.latency_grid(params, [0.0]), backend="pallas",
-                compute_lam=False)

@@ -291,14 +291,24 @@ def test_policy_shard_validation_and_wire(params):
     ExecPolicy(shard=2).validate()
 
 
-def test_compute_lam_flag_wins_over_query_defaults(params):
-    """run(Query(...), compute_lam=False) must not silently pay for λ —
-    the legacy flag overrides the Query's defaulted outputs tuple."""
+def test_compute_lam_flag_wins_over_query_defaults(params, monkeypatch):
+    """run(Query(...), outputs=("T",)) must not silently pay for λ — the
+    keyword overrides the Query's defaulted outputs tuple, and no λ
+    program is built or run."""
     g = synth.stencil2d(2, 2, 2, params=params)
     eng = Engine(g, params=params, policy=ExecPolicy(cache=None))
     batch = sweep.latency_grid(params, [0.0, 5.0])
-    res = eng.run(Query(scenarios=batch), compute_lam=False)
+    asked = []
+    real = sweep_engine._get_forward
+
+    def spy(kind, want_lam=False, *a, **kw):
+        asked.append(want_lam)
+        return real(kind, want_lam, *a, **kw)
+
+    monkeypatch.setattr(sweep_engine, "_get_forward", spy)
+    res = eng.run(Query(scenarios=batch), outputs=("T",))
     assert res.lam is None and res.rho is None
+    assert asked == [False]
 
 
 def test_argbest_rejects_bare_graph_axis(params):
